@@ -352,10 +352,10 @@ def run_service(backend: str = "blocked", concurrency: int = 8,
         # each shard count, same index + workload (per-worker cache budget
         # so the fleet's aggregate cache grows with the shard count)
         from repro.serving import RankingRouter
-        devs = jax.devices()
+        from repro.serving.sharded import worker_devices
         shard_qps = {}
         for n_sh in shard_counts:
-            devices = devs[:n_sh] if len(devs) >= n_sh else None
+            devices = worker_devices(n_sh)
             router = RankingRouter(params, cfg, idx, n_shards=n_sh,
                                    devices=devices, micro_batch=micro_batch,
                                    fused=True, doc_cache_mb=doc_cache_mb)

@@ -32,6 +32,18 @@ def smoke_config(l: int = 2, compress_dim: int = 16,
         l=l, max_query_len=8, max_doc_len=40, compress_dim=compress_dim)
 
 
+#: the entry points' ``--config`` choices: ``base`` is the paper's ranker
+#: at its published widths, ``smoke`` the 4-layer d=64 toy for CPU runs
+CONFIGS = {"smoke": smoke_config, "base": full_config}
+
+
+def config(name: str, **overrides) -> PreTTRConfig:
+    """``CONFIGS[name]`` with ``overrides``; a ``None`` override keeps the
+    config's own default (an unset command-line flag)."""
+    return CONFIGS[name](**{k: v for k, v in overrides.items()
+                            if v is not None})
+
+
 def spec() -> ArchSpec:
     return ArchSpec(
         name="prettr-bert", family="prettr", config=full_config(),

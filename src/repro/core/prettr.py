@@ -122,22 +122,13 @@ def _cls_only_layer(lp, x, cfg: T.TransformerConfig, *, positions, valid):
     attention — paper §6.3: a decode-shaped attention, dispatched through
     the backend registry (the pallas impl is the flash-decode kernel).
     x: [B, S, d] -> cls rep [B, d]."""
-    b, s, _ = x.shape
+    b = x.shape[0]
     dh = cfg.dh
     cd = cfg.compute_dtype
     h = L.apply_norm(lp["ln1"], x, cfg.norm)
     p = lp["attn"]
-    q = (h[:, :1] @ p["wq"].astype(cd)).reshape(b, 1, cfg.n_heads, dh)
-    k = (h @ p["wk"].astype(cd)).reshape(b, s, cfg.n_kv_heads, dh)
-    v = (h @ p["wv"].astype(cd)).reshape(b, s, cfg.n_kv_heads, dh)
-    if cfg.qkv_bias:
-        q = q + p["bq"].astype(cd).reshape(cfg.n_heads, dh)
-        k = k + p["bk"].astype(cd).reshape(cfg.n_kv_heads, dh)
-        v = v + p["bv"].astype(cd).reshape(cfg.n_kv_heads, dh)
-    if cfg.rope:
-        q = L.rope(q, positions[:, :1], base=cfg.rope_base,
-                   fraction=cfg.rope_fraction)
-        k = L.rope(k, positions, base=cfg.rope_base, fraction=cfg.rope_fraction)
+    q = T.project_q(p, h[:, :1], cfg, positions=positions[:, :1])
+    k, v = T.project_kv(p, h, cfg, positions=positions)
     # bidirectional single-row attention over the full sequence
     k_pos = positions
     q_pos = jnp.full((b, 1), jnp.iinfo(jnp.int32).max // 2, jnp.int32)
@@ -324,7 +315,8 @@ class PagedDocKV:
     dense per-batch copy (the pallas impl walks ``page_table`` in its
     index maps; the reference impls gather pages in-jit).
 
-    ``k``/``v``: [P, page, Hkv, Dh] pools; ``valid``: [P, page] int pool
+    ``k``/``v``: [P, Hkv, page, Dh] head-major pools (the device doc
+    cache's K/V layout); ``valid``: [P, page] int pool
     (the cache's page 0 is all-zero, so padded page-table tails mask
     themselves); ``page_table``: [B, nP] i32; ``k_scale``/``v_scale``:
     optional [P, page, 1] fp32 per-token dequant scale pools when the
@@ -392,10 +384,9 @@ def prepare_join(params, cfg: PreTTRConfig, q_reps, q_valid, doc_store,
     layer-``l`` streams (fused path only) in one of three forms:
     ``(k, v)`` raw floats each [B, Ld, n_kv_heads * dh];
     ``(k, v, k_scale, v_scale)`` int8 payload plus [B, Ld] fp32 scales
-    (dequantized inside the join impl); or a :class:`PagedDocKV` whose
-    pools may arrive flat ([P, page, d_kv] / [P, page] scales) straight
-    from the device doc cache — they are reshaped to kernel page layout
-    here."""
+    (dequantized inside the join impl); or a :class:`PagedDocKV` straight
+    from the device doc cache (head-major [P, Hkv, page, Dh] K/V pools,
+    [P, page] scale pools — reshaped to the kernel's [P, page, 1] here)."""
     bcfg = cfg.backbone
     x_d = _decode_doc_store(params, cfg, doc_store)
     doc_k = doc_v = doc_k_scale = doc_v_scale = doc_kv_paged = None
@@ -407,11 +398,9 @@ def prepare_join(params, cfg: PreTTRConfig, q_reps, q_valid, doc_store,
         b, ld = x_d.shape[0], x_d.shape[1]
         hkv, dh = bcfg.n_kv_heads, bcfg.dh
         if isinstance(doc_kv, PagedDocKV):
-            page = doc_kv.k.shape[1]
+            page = doc_kv.k.shape[2]
             doc_kv_paged = PagedDocKV(
-                k=doc_kv.k.reshape(-1, page, hkv, dh),
-                v=doc_kv.v.reshape(-1, page, hkv, dh),
-                valid=doc_kv.valid,
+                k=doc_kv.k, v=doc_kv.v, valid=doc_kv.valid,
                 page_table=doc_kv.page_table,
                 k_scale=(None if doc_kv.k_scale is None
                          else doc_kv.k_scale.reshape(-1, page, 1)),

@@ -19,7 +19,8 @@ from __future__ import annotations
 import dataclasses
 from typing import Mapping, Union
 
-from jax.sharding import Mesh, PartitionSpec
+import jax
+from jax.sharding import AxisType, Mesh, PartitionSpec
 
 # one logical axis maps to a mesh axis, an ordered tuple of mesh axes
 # (tried left to right), or None / absent (replicated)
@@ -46,6 +47,19 @@ class ShardingRules:
         if logical is None:
             return ()
         return _as_tuple(self.rules.get(logical))
+
+
+def make_mesh(shape, axes) -> Mesh:
+    """A device mesh whose axes are all ``Auto``.
+
+    The rules here place only what model code annotates (through
+    ``with_sharding_constraint``) and leave the rest to the partitioner.
+    ``jax.make_mesh`` defaults to ``Explicit`` axes, under which every op
+    computes its output sharding from its inputs' — and a gather of an
+    FSDP table (``embed -> data``) by batch-sharded ids (``batch -> data``)
+    then names ``data`` twice in one spec."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def default_rules(mesh: Mesh) -> ShardingRules:

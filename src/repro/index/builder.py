@@ -9,10 +9,12 @@ a corpus and writes a format-v2 index (``manifest.msgpack`` +
 * **Fixed-shape batches** — documents are packed to ``[batch, max_doc_len]``
   (last batch padded with empty rows, results dropped), so the whole build
   hits one jit cache entry.
-* **Data-parallel over the ``repro.dist`` mesh** — given a mesh, each batch
-  is sharded over the ``data`` axis (weights replicated); every example's
-  computation is row-independent, so the sharded build is doc-for-doc
-  bit-identical to the single-host build.
+* **Data-parallel over the ``repro.dist`` mesh** — given a mesh, each
+  device encodes its own ``batch_size`` rows (weights replicated).  XLA's
+  output differs at the ulp across batch *shapes* but not across row
+  positions, so keeping the per-device shape equal to the single-host one
+  is what makes the sharded build doc-for-doc bit-identical to the
+  single-host build (and replayable by ``verify_index``).
 * **Overlapped host writes** — a writer thread materializes each batch on
   the host, codec-encodes it, and appends to the shard files while the
   device encodes the *next* batch (the PR-3 serving prefetch thread, in
@@ -167,8 +169,10 @@ class IndexBuilder:
         report = builder.build(doc_token_lists)
         index = TermRepIndex.open(out_dir)
 
-    ``mesh`` (optional): a jax Mesh with a ``"data"`` axis; batches are
-    sharded over it for data-parallel encoding.  ``writer_depth`` bounds
+    ``mesh`` (optional): a jax Mesh with a ``"data"`` axis; each encode
+    step shards ``batch_size`` rows per device over it (the build's
+    program is left to the partitioner, so the mesh's axes are used as
+    ``Auto`` whatever their declared type).  ``writer_depth`` bounds
     the in-flight device batches the writer thread may lag behind
     (``0`` = synchronous writes, for debugging).  ``backend`` reroutes the
     encode through a compute-backend family exactly as on the serving
@@ -250,13 +254,19 @@ class IndexBuilder:
         self.out_dir = out_dir
         self.params = params
         self.n_shards = max(1, int(n_shards))
+        if mesh is not None:
+            from jax.sharding import AxisType, Mesh
+            mesh = Mesh(mesh.devices, mesh.axis_names,
+                        axis_types=(AxisType.Auto,) * len(mesh.axis_names))
         self.mesh = mesh
         self.writer_depth = max(0, writer_depth)
         self.rep_dim = cfg.compress_dim or cfg.backbone.d_model
         self.kv_dim = cfg.backbone.n_kv_heads * cfg.backbone.dh
         ndev = mesh.size if mesh is not None else 1
-        # fixed jit shape, divisible by the data-parallel mesh
-        self.batch_size = -(-max(1, batch_size) // ndev) * ndev
+        # fixed per-device jit shape (the manifest's encode_batch); one
+        # encode step takes batch_size rows on every device
+        self.batch_size = max(1, batch_size)
+        self._step_rows = self.batch_size * ndev
         self._params_replicated = None
         self._encode = jax.jit(
             lambda p, d, v: P.precompute_docs(p, self.cfg, d, v))
@@ -311,8 +321,8 @@ class IndexBuilder:
         ``(reps, valid)`` (valid padded to the batch shape, for the
         salience pass)."""
         n = len(tokens)
-        if n < self.batch_size:
-            pad = self.batch_size - n
+        if n < self._step_rows:
+            pad = self._step_rows - n
             tokens = np.concatenate(
                 [tokens, np.zeros((pad, tokens.shape[1]), tokens.dtype)])
             valid = np.concatenate(
@@ -336,8 +346,8 @@ class IndexBuilder:
         on their valid-token reps."""
         buf = []
         n_fit = min(len(docs), self._fit_sample)
-        for lo in range(0, n_fit, self.batch_size):
-            chunk = docs[lo: lo + self.batch_size]
+        for lo in range(0, n_fit, self._step_rows):
+            chunk = docs[lo: lo + self._step_rows]
             tokens, lengths, valid = pack_doc_batch(
                 chunk, self.cfg.max_doc_len)
             reps_dev, _ = self._device_batch(tokens, valid)
@@ -388,8 +398,8 @@ class IndexBuilder:
 
         encode_s = 0.0
         try:
-            for lo in range(0, n_docs, self.batch_size):
-                chunk = docs[lo: lo + self.batch_size]
+            for lo in range(0, n_docs, self._step_rows):
+                chunk = docs[lo: lo + self._step_rows]
                 tokens, lengths, valid = pack_doc_batch(
                     chunk, self.cfg.max_doc_len)
                 t0 = time.perf_counter()

@@ -8,7 +8,9 @@ laid out ``[B, Hkv, R, D]``.
 
 Grid ``(B, Hkv, nK)`` with the KV axis innermost; online-softmax state in
 VMEM scratch across KV tiles.  Sliding-window archs (Gemma3 local layers)
-mask ``k_pos <= qpos - window``.
+mask ``k_pos <= qpos - window``.  TPU block shapes: validity rides
+``[B, 1, S]`` rows in ``(1, block_k)`` blocks (``block_k`` a multiple of
+128 or the whole padded length).
 """
 from __future__ import annotations
 
@@ -52,7 +54,7 @@ def _decode_kernel(lengths_ref, q_ref, k_ref, v_ref, valid_ref, o_ref,
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        mask = (k_pos < length) & (valid_ref[...] > 0)
+        mask = (k_pos < length) & (valid_ref[0] > 0)
         if window > 0:
             mask &= (q_pos - k_pos) < window
         s = jnp.where(mask, s, NEG_INF)
@@ -75,7 +77,7 @@ def _decode_kernel(lengths_ref, q_ref, k_ref, v_ref, valid_ref, o_ref,
 def flash_decode_pallas(q, k, v, lengths, k_valid, *, window: int,
                         block_k: int, interpret: bool):
     """q: [B, Hkv, R, D]; k, v: [B, Hkv, S, D]; lengths: [B]; k_valid:
-    [B, S] i32 (0 = masked — non-prefix validity for the CLS-only layer;
+    [B, 1, S] i32 (0 = masked — non-prefix validity for the CLS-only layer;
     ``lengths`` stays the tile-skip bound covering every valid index)."""
     b, hkv, r, d = q.shape
     s = k.shape[2]
@@ -94,7 +96,8 @@ def flash_decode_pallas(q, k, v, lengths, k_valid, *, window: int,
                              lambda b, h, ik, L: (b, h, ik, 0)),
                 pl.BlockSpec((1, 1, block_k, d),
                              lambda b, h, ik, L: (b, h, ik, 0)),
-                pl.BlockSpec((1, block_k), lambda b, h, ik, L: (b, ik)),
+                pl.BlockSpec((1, 1, block_k),
+                             lambda b, h, ik, L: (b, 0, ik)),
             ],
             out_specs=pl.BlockSpec((1, 1, r, d),
                                    lambda b, h, ik, L: (b, h, 0, 0)),

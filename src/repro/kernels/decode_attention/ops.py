@@ -8,10 +8,7 @@ import jax.numpy as jnp
 
 from repro.kernels.decode_attention.kernel import flash_decode_pallas
 from repro.kernels.masking import last_valid_lengths
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+from repro.kernels.tpu import interpret_mode
 
 
 @functools.partial(jax.jit, static_argnames=("window", "block_k", "interpret"))
@@ -24,7 +21,7 @@ def flash_decode_attention(q, k, v, lengths=None, k_valid=None, *,
     defaults to one past the last valid index per row.
     Returns [B, Hq, 1, D]."""
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = interpret_mode()
     b, hq, _, d = q.shape
     hkv, s = k.shape[1], k.shape[2]
     n_rep = hq // hkv
@@ -38,9 +35,9 @@ def flash_decode_attention(q, k, v, lengths=None, k_valid=None, *,
     if pad:
         k = jnp.pad(k, ((0, 0), (0, 0), (0, pad), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, 0), (0, pad), (0, 0)))
-        k_valid = jnp.pad(k_valid.astype(jnp.int32), ((0, 0), (0, pad)))
+    k_valid = jnp.pad(k_valid.astype(jnp.int32), ((0, 0), (0, pad)))
     qg = q[:, :, 0].reshape(b, hkv, n_rep, d)
     out = flash_decode_pallas(qg, k, v, lengths.astype(jnp.int32),
-                              k_valid.astype(jnp.int32),
+                              k_valid[:, None, :],
                               window=window, block_k=bk, interpret=interpret)
     return out.reshape(b, hq, 1, d)

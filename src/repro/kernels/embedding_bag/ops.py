@@ -7,10 +7,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.embedding_bag.kernel import embedding_bag_pallas
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+from repro.kernels.tpu import interpret_mode
 
 
 @functools.partial(jax.jit, static_argnames=("mode", "interpret"))
@@ -19,7 +16,7 @@ def embedding_bag_pallas_op(table, ids, weights=None, *, mode: str = "sum",
     """table: [rows, dim]; ids: [n_bags, max_nnz]; weights optional (0 pads).
     -> [n_bags, dim]."""
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = interpret_mode()
     if weights is None:
         weights = jnp.ones(ids.shape, jnp.float32)
     return embedding_bag_pallas(table, ids.astype(jnp.int32),

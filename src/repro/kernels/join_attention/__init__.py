@@ -4,6 +4,7 @@ from repro.kernels.join_attention.ref import (dequantize_kv,
                                               join_attention_ref,
                                               join_attention_ref_paged,
                                               join_attention_ref_quant,
+                                              kv_pages_to_dense,
                                               pages_to_dense)
 
 __all__ = [
@@ -13,5 +14,6 @@ __all__ = [
     "join_attention_ref_quant",
     "join_attention_ref_paged",
     "dequantize_kv",
+    "kv_pages_to_dense",
     "pages_to_dense",
 ]

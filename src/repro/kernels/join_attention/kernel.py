@@ -29,13 +29,20 @@ Two orthogonal extensions serve the index-fed doc segment:
   HBM read shrinks to the 1-byte payload.  Dequantizing the rows before
   the dot (rather than folding scales into scores/probabilities) keeps
   the kernel bit-exact against decode-then-attend.
-* **Paged doc segment** (``paged=True``): the doc K/V live in fixed-size
-  token-page pools ``[P, page, Hkv, D]`` (the device doc cache's layout)
-  and a scalar-prefetched page table ``[B, nP]`` maps each (row, tile) to
-  its pool page — the doc-segment index maps walk the page table, so a
-  batch is scored straight out of the cache pools without materializing
-  a per-batch dense copy.  Page validity rides a ``[P, page]`` pool the
-  same way.
+* **Paged doc segment** (:func:`join_attention_pallas_paged`): the doc
+  K/V live in fixed-size token-page pools ``[P, Hkv, page, D]`` (the
+  device doc cache's layout) and a scalar-prefetched page table
+  ``[B, nP]`` maps each (row, tile) to its pool page — the doc-segment
+  index maps walk the page table, so a batch is scored straight out of
+  the cache pools without materializing a per-batch dense copy.  Page
+  validity rides a ``[P, 1, page]`` pool the same way.
+
+TPU block shapes: the last two dims of every block are whole ``(rows, D)``
+tiles — K/V tiles ``(block_k | page, D)``, validity as ``[B, 1, L]`` rows
+with ``(1, block_k)`` blocks (``block_k`` a multiple of 128 or the whole
+padded length), per-token scales as ``(block_k, 1)`` columns.  fp16 doc
+K/V (raw fp16 index bytes) arrive bitcast to ``uint16`` and are widened in
+registers.
 """
 from __future__ import annotations
 
@@ -47,11 +54,21 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.tpu import f16_bits_to_f32
+
 NEG_INF = -1e30
 
 
+def _widen(x):
+    """A doc K/V tile in f32: ``uint16`` carries fp16 bit patterns (the
+    TPU's Pallas compiler has no fp16), anything else converts."""
+    if x.dtype == jnp.uint16:
+        return f16_bits_to_f32(x)
+    return x.astype(jnp.float32)
+
+
 def _join_kernel(dlen_ref, *refs, block_k: int, scale: float,
-                 dequant: bool, paged: bool):
+                 dequant: bool):
     q_ref, kq_ref, vq_ref, kd_ref, vd_ref = refs[:5]
     i = 5
     if dequant:
@@ -72,7 +89,7 @@ def _join_kernel(dlen_ref, *refs, block_k: int, scale: float,
         vq = vq_ref[0, 0].astype(jnp.float32)
         s = jax.lax.dot_general(q, kq, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
-        s = jnp.where(qval_ref[...] > 0, s, NEG_INF)   # [1, Lqp] broadcast
+        s = jnp.where(qval_ref[0] > 0, s, NEG_INF)     # [1, Lqp] broadcast
         m = jnp.max(s, axis=1, keepdims=True)
         p = jnp.exp(s - m)
         m_scr[...] = m
@@ -85,12 +102,8 @@ def _join_kernel(dlen_ref, *refs, block_k: int, scale: float,
     @pl.when(dlen_ref[b] > k0)                         # doc tile beyond length
     def _doc_tile():
         q = q_ref[0, 0].astype(jnp.float32)
-        if paged:                                      # pool page [page, D]
-            kd = kd_ref[0, :, 0].astype(jnp.float32)
-            vd = vd_ref[0, :, 0].astype(jnp.float32)
-        else:                                          # dense tile [bk, D]
-            kd = kd_ref[0, 0].astype(jnp.float32)
-            vd = vd_ref[0, 0].astype(jnp.float32)
+        kd = _widen(kd_ref[0, 0])                      # [bk | page, D]
+        vd = _widen(vd_ref[0, 0])
         if dequant:
             # widen the raw int8 rows in registers: per-token fp32 scales
             # arrive as a [bk, 1] column, broadcasting over D — identical
@@ -101,7 +114,7 @@ def _join_kernel(dlen_ref, *refs, block_k: int, scale: float,
         s = jax.lax.dot_general(q, kd, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        mask = (k_pos < dlen_ref[b]) & (dval_ref[...] > 0)
+        mask = (k_pos < dlen_ref[b]) & (dval_ref[0] > 0)
         s = jnp.where(mask, s, NEG_INF)
 
         m_prev = m_scr[...]
@@ -124,7 +137,7 @@ def _paged_shim(pt_ref, dlen_ref, *refs, block_k, scale, dequant):
     # only consumed by the BlockSpec index maps, never by the body
     del pt_ref
     _join_kernel(dlen_ref, *refs, block_k=block_k, scale=scale,
-                 dequant=dequant, paged=True)
+                 dequant=dequant)
 
 
 def join_attention_pallas(q, kq, vq, kd, vd, dlen, kq_valid, kd_valid, *,
@@ -132,8 +145,9 @@ def join_attention_pallas(q, kq, vq, kd, vd, dlen, kq_valid, kd_valid, *,
                           kd_scales=None, vd_scales=None):
     """q: [B, Hq, Sq, D]; kq, vq: [B, Hkv, Lq, D]; kd, vd: [B, Hkv, Ld, D];
     dlen: [B] i32 (doc-segment tile-skip bound, covering every valid doc
-    index); kq_valid: [B, Lq] i32; kd_valid: [B, Ld] i32.  Sq/Ld must be
-    multiples of block_q/block_k and Lq a sublane multiple (ops.py pads).
+    index); kq_valid: [B, 1, Lq] i32; kd_valid: [B, 1, Ld] i32.  Sq/Ld
+    must be multiples of block_q/block_k and Lq a sublane multiple (ops.py
+    pads).
 
     ``kd_scales``/``vd_scales`` (optional, both or neither): per-token fp32
     dequant scales [B, Ld, 1] for raw-int8 ``kd``/``vd`` — the KV tiles are
@@ -147,7 +161,7 @@ def join_attention_pallas(q, kq, vq, kd, vd, dlen, kq_valid, kd_valid, *,
     scale = 1.0 / math.sqrt(d)
 
     kern = functools.partial(_join_kernel, block_k=block_k, scale=scale,
-                             dequant=dequant, paged=False)
+                             dequant=dequant)
     grid = (b, hq, sq // block_q, ld // block_k)
     in_specs = [
         pl.BlockSpec((1, 1, block_q, d),
@@ -169,8 +183,8 @@ def join_attention_pallas(q, kq, vq, kd, vd, dlen, kq_valid, kd_valid, *,
         ]
         operands += [kd_scales, vd_scales]
     in_specs += [
-        pl.BlockSpec((1, lq), lambda b, h, iq, ik, L: (b, 0)),
-        pl.BlockSpec((1, block_k), lambda b, h, iq, ik, L: (b, ik)),
+        pl.BlockSpec((1, 1, lq), lambda b, h, iq, ik, L: (b, 0, 0)),
+        pl.BlockSpec((1, 1, block_k), lambda b, h, iq, ik, L: (b, 0, ik)),
     ]
     operands += [kq_valid, kd_valid]
     return pl.pallas_call(
@@ -200,11 +214,12 @@ def join_attention_pallas_paged(q, kq, vq, kd_pages, vd_pages, page_table,
     and the doc-segment index maps walk the scalar-prefetched page table.
 
     q: [B, Hq, Sq, D]; kq, vq: [B, Hkv, Lq, D];
-    kd_pages, vd_pages: [P, page, Hkv, D] token-page pools;
+    kd_pages, vd_pages: [P, Hkv, page, D] token-page pools;
     page_table: [B, nP] i32 pool page per (row, doc tile) — tail entries
     point at the cache's all-zero page and are masked by ``dlen``;
     dlen: [B] i32 valid length of the assembled doc row;
-    dval_pages: [P, page] i32 page-resident validity pool;
+    kq_valid: [B, 1, Lq] i32; dval_pages: [P, 1, page] i32 page-resident
+    validity pool;
     kd_scale_pages / vd_scale_pages: optional [P, page, 1] fp32 per-token
     dequant scale pools for raw-int8 KV pools.
 
@@ -213,7 +228,7 @@ def join_attention_pallas_paged(q, kq, vq, kd_pages, vd_pages, page_table,
     Returns [B, Hq, Sq, D] with the doc segment of length nP * page."""
     b, hq, sq, d = q.shape
     hkv, lq = kq.shape[1], kq.shape[2]
-    page = kd_pages.shape[1]
+    page = kd_pages.shape[2]
     n_pages = page_table.shape[1]
     assert sq % block_q == 0
     dequant = kd_scale_pages is not None
@@ -231,10 +246,10 @@ def join_attention_pallas_paged(q, kq, vq, kd_pages, vd_pages, page_table,
         pl.BlockSpec((1, 1, lq, d),
                      lambda b, h, iq, ik, pt, L: (b, h // n_rep, 0, 0)),
         # the page-table walk: tile ik of row b reads pool page pt[b, ik]
-        pl.BlockSpec((1, page, 1, d),
-                     lambda b, h, iq, ik, pt, L: (pt[b, ik], 0, h // n_rep, 0)),
-        pl.BlockSpec((1, page, 1, d),
-                     lambda b, h, iq, ik, pt, L: (pt[b, ik], 0, h // n_rep, 0)),
+        pl.BlockSpec((1, 1, page, d),
+                     lambda b, h, iq, ik, pt, L: (pt[b, ik], h // n_rep, 0, 0)),
+        pl.BlockSpec((1, 1, page, d),
+                     lambda b, h, iq, ik, pt, L: (pt[b, ik], h // n_rep, 0, 0)),
     ]
     operands = [q, kq, vq, kd_pages, vd_pages]
     if dequant:
@@ -246,8 +261,9 @@ def join_attention_pallas_paged(q, kq, vq, kd_pages, vd_pages, page_table,
         ]
         operands += [kd_scale_pages, vd_scale_pages]
     in_specs += [
-        pl.BlockSpec((1, lq), lambda b, h, iq, ik, pt, L: (b, 0)),
-        pl.BlockSpec((1, page), lambda b, h, iq, ik, pt, L: (pt[b, ik], 0)),
+        pl.BlockSpec((1, 1, lq), lambda b, h, iq, ik, pt, L: (b, 0, 0)),
+        pl.BlockSpec((1, 1, page),
+                     lambda b, h, iq, ik, pt, L: (pt[b, ik], 0, 0)),
     ]
     operands += [kq_valid, dval_pages]
     return pl.pallas_call(

@@ -12,10 +12,35 @@ import jax.numpy as jnp
 from repro.kernels.join_attention.kernel import (join_attention_pallas,
                                                  join_attention_pallas_paged)
 from repro.kernels.masking import last_valid_lengths
+from repro.kernels.tpu import interpret_mode, sublane
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def _kernel_kv(x):
+    """fp16 doc K/V cross the kernel boundary as ``uint16`` bits (the
+    kernel widens them in registers); other dtypes pass as they are."""
+    return (jax.lax.bitcast_convert_type(x, jnp.uint16)
+            if x.dtype == jnp.float16 else x)
+
+
+def _q_segment_operands(q, kq, vq, kq_valid, block_q):
+    """Pad ``q`` to whole ``bq`` row blocks (at least one native tile of
+    its dtype — the CLS row is one row) and the whole-block query-segment
+    K/V to a sublane multiple; validity goes in the kernels' ``[B, 1, L]``
+    row layout."""
+    b, _, sq, _ = q.shape
+    lq = kq.shape[2]
+    if kq_valid is None:
+        kq_valid = jnp.ones((b, lq), jnp.int32)
+    bq = min(block_q, max(sublane(q.dtype), sq))
+    pad_q = (-sq) % bq
+    pad_lq = (-lq) % sublane(kq.dtype)
+    if pad_q:
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, pad_q), (0, 0)))
+    if pad_lq:
+        kq = jnp.pad(kq, ((0, 0), (0, 0), (0, pad_lq), (0, 0)))
+        vq = jnp.pad(vq, ((0, 0), (0, 0), (0, pad_lq), (0, 0)))
+    kq_valid = jnp.pad(kq_valid.astype(jnp.int32), ((0, 0), (0, pad_lq)))
+    return q, kq, vq, kq_valid[:, None, :], bq
 
 
 @functools.partial(jax.jit, static_argnames=("block_q", "block_k",
@@ -43,30 +68,19 @@ def join_flash_attention(q, kq, vq, kd, vd, kq_valid=None, kd_valid=None,
     Returns [B, Hq, Sq, D].
     """
     if interpret is None:
-        interpret = not _on_tpu()
-    b, hq, sq, d = q.shape
-    lq, ld = kq.shape[2], kd.shape[2]
-    if kq_valid is None:
-        kq_valid = jnp.ones((b, lq), jnp.int32)
+        interpret = interpret_mode()
+    b, sq, ld = q.shape[0], q.shape[2], kd.shape[2]
     if kd_valid is None:
         kd_valid = jnp.ones((b, ld), jnp.int32)
     dlen = last_valid_lengths(kd_valid, ld)
-
-    bq = min(block_q, max(8, sq))
+    q, kq, vq, kq_valid, bq = _q_segment_operands(q, kq, vq, kq_valid,
+                                                  block_q)
     bk = min(block_k, max(8, ld))
-    pad_q = (-sq) % bq
-    pad_lq = max(8, -(-lq // 8) * 8) - lq   # whole-block q segment: 8-mult
     pad_d = (-ld) % bk
-    if pad_q:
-        q = jnp.pad(q, ((0, 0), (0, 0), (0, pad_q), (0, 0)))
-    if pad_lq:
-        kq = jnp.pad(kq, ((0, 0), (0, 0), (0, pad_lq), (0, 0)))
-        vq = jnp.pad(vq, ((0, 0), (0, 0), (0, pad_lq), (0, 0)))
-        kq_valid = jnp.pad(kq_valid.astype(jnp.int32), ((0, 0), (0, pad_lq)))
     if pad_d:
         kd = jnp.pad(kd, ((0, 0), (0, 0), (0, pad_d), (0, 0)))
         vd = jnp.pad(vd, ((0, 0), (0, 0), (0, pad_d), (0, 0)))
-        kd_valid = jnp.pad(kd_valid.astype(jnp.int32), ((0, 0), (0, pad_d)))
+    kd_valid = jnp.pad(kd_valid.astype(jnp.int32), ((0, 0), (0, pad_d)))
     if kd_scales is not None:
         kd_scales = kd_scales.astype(jnp.float32)
         vd_scales = vd_scales.astype(jnp.float32)
@@ -75,9 +89,9 @@ def join_flash_attention(q, kq, vq, kd, vd, kq_valid=None, kd_valid=None,
             vd_scales = jnp.pad(vd_scales, ((0, 0), (0, pad_d)))
         kd_scales = kd_scales[..., None]    # [B, Ld, 1] — row-broadcast
         vd_scales = vd_scales[..., None]
-    out = join_attention_pallas(q, kq, vq, kd, vd, dlen.astype(jnp.int32),
-                                kq_valid.astype(jnp.int32),
-                                kd_valid.astype(jnp.int32),
+    out = join_attention_pallas(q, kq, vq, _kernel_kv(kd), _kernel_kv(vd),
+                                dlen.astype(jnp.int32),
+                                kq_valid, kd_valid[:, None, :],
                                 block_q=bq, block_k=bk, interpret=interpret,
                                 kd_scales=kd_scales, vd_scales=vd_scales)
     return out[:, :, :sq]
@@ -94,7 +108,7 @@ def join_flash_attention_paged(q, kq, vq, kd_pages, vd_pages, page_table,
     per-batch dense KV copy is ever materialized.
 
     q: [B, Hq, Sq, D]; kq, vq: [B, Hkv, Lq, D];
-    kd_pages, vd_pages: [P, page, Hkv, D] pools (``page`` a sublane
+    kd_pages, vd_pages: [P, Hkv, page, D] pools (``page`` a sublane
     multiple — the cache rounds it up); page_table: [B, nP] i32 pool page
     per (row, doc tile), tail entries pointing at the cache's all-zero
     page 0; dval_pages: [P, page] token-validity pool (page 0 is all-zero,
@@ -103,33 +117,23 @@ def join_flash_attention_paged(q, kq, vq, kd_pages, vd_pages, page_table,
     Returns [B, Hq, Sq, D]; the doc segment spans nP * page assembled
     positions."""
     if interpret is None:
-        interpret = not _on_tpu()
-    b, hq, sq, d = q.shape
-    lq = kq.shape[2]
-    if kq_valid is None:
-        kq_valid = jnp.ones((b, lq), jnp.int32)
+        interpret = interpret_mode()
+    b, sq = q.shape[0], q.shape[2]
     page_table = page_table.astype(jnp.int32)
     dval_pages = dval_pages.astype(jnp.int32)
     # valid length of each assembled row, gathered from the validity pool
     # (tiny [B, nP*page] int gather; the KV pools are never densified)
     dval_rows = dval_pages[page_table].reshape(b, -1)
     dlen = last_valid_lengths(dval_rows, dval_rows.shape[1])
-
-    bq = min(block_q, max(8, sq))
-    pad_q = (-sq) % bq
-    pad_lq = max(8, -(-lq // 8) * 8) - lq
-    if pad_q:
-        q = jnp.pad(q, ((0, 0), (0, 0), (0, pad_q), (0, 0)))
-    if pad_lq:
-        kq = jnp.pad(kq, ((0, 0), (0, 0), (0, pad_lq), (0, 0)))
-        vq = jnp.pad(vq, ((0, 0), (0, 0), (0, pad_lq), (0, 0)))
-        kq_valid = jnp.pad(kq_valid.astype(jnp.int32), ((0, 0), (0, pad_lq)))
+    q, kq, vq, kq_valid, bq = _q_segment_operands(q, kq, vq, kq_valid,
+                                                  block_q)
     if kd_scale_pages is not None:
         kd_scale_pages = kd_scale_pages.astype(jnp.float32)
         vd_scale_pages = vd_scale_pages.astype(jnp.float32)
     out = join_attention_pallas_paged(
-        q, kq, vq, kd_pages, vd_pages, page_table, dlen.astype(jnp.int32),
-        kq_valid.astype(jnp.int32), dval_pages,
+        q, kq, vq, _kernel_kv(kd_pages), _kernel_kv(vd_pages), page_table,
+        dlen.astype(jnp.int32),
+        kq_valid, dval_pages[:, None, :],
         block_q=bq, interpret=interpret,
         kd_scale_pages=kd_scale_pages, vd_scale_pages=vd_scale_pages)
     return out[:, :, :sq]

@@ -61,15 +61,23 @@ def pages_to_dense(pages, page_table):
     return g.reshape((g.shape[0], g.shape[1] * g.shape[2]) + g.shape[3:])
 
 
+def kv_pages_to_dense(pages, page_table):
+    """Densify K/V pools in the paged kernel's head-major layout.
+    pages: [P, Hkv, page, D]; page_table: [B, nP] i32.
+    Returns token-major [B, nP * page, Hkv, D]."""
+    g = jnp.moveaxis(pages[page_table], 2, 3)    # [B, nP, page, Hkv, D]
+    return g.reshape((g.shape[0], g.shape[1] * g.shape[2]) + g.shape[3:])
+
+
 def join_attention_ref_paged(q, kq, vq, kd_pages, vd_pages, page_table,
                              dval_pages, kq_valid=None,
                              kd_scale_pages=None, vd_scale_pages=None):
     """Densify-then-attend oracle for the paged doc segment: gather pages
-    into dense [B, Ld, Hkv, D] rows, optionally dequantize, then run the
+    into dense [B, Hkv, Ld, D] rows, optionally dequantize, then run the
     fp32 oracle.  Pool layouts match the paged kernel
-    ([P, page, Hkv, D] KV, [P, page] validity, [P, page, 1] scales)."""
-    kd = jnp.moveaxis(pages_to_dense(kd_pages, page_table), 2, 1)
-    vd = jnp.moveaxis(pages_to_dense(vd_pages, page_table), 2, 1)
+    ([P, Hkv, page, D] KV, [P, page] validity, [P, page, 1] scales)."""
+    kd = jnp.moveaxis(kv_pages_to_dense(kd_pages, page_table), 2, 1)
+    vd = jnp.moveaxis(kv_pages_to_dense(vd_pages, page_table), 2, 1)
     kd_valid = pages_to_dense(dval_pages, page_table)
     if kd_scale_pages is not None:
         kd_scales = pages_to_dense(kd_scale_pages, page_table)[..., 0]

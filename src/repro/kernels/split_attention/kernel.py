@@ -12,7 +12,9 @@ Grid: ``(B, Hq, nQ, nK)`` — the KV axis iterates innermost so the online
 softmax state (m, l, acc) lives in VMEM scratch across KV tiles (the
 standard sequential-grid TPU flash pattern).  GQA is handled in the K/V
 index maps (head ``h`` reads KV head ``h * Hkv // Hq``) — no repeated KV is
-materialized.
+materialized.  TPU block shapes: K/V tiles ``(block_k, D)``, validity as
+``[B, 1, Skv]`` rows with ``(1, block_k)`` blocks (``block_k`` a multiple
+of 128 or the whole padded length).
 """
 from __future__ import annotations
 
@@ -82,7 +84,7 @@ def _attn_kernel(lengths_ref, *refs, block_q: int, block_k: int,
 
         q_pos = q0 + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
         k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-        mask = (k_pos < lengths_ref[b]) & (valid_ref[...] > 0)
+        mask = (k_pos < lengths_ref[b]) & (valid_ref[0] > 0)
         if causal:
             mask &= k_pos <= q_pos
         if window > 0:
@@ -111,7 +113,7 @@ def flash_attention_pallas(q, k, v, lengths, k_valid, *, causal: bool,
                            block_k: int, interpret: bool,
                            k_scales=None, v_scales=None):
     """q: [B, Hq, Sq, D]; k, v: [B, Hkv, Skv, D]; lengths: [B] i32;
-    k_valid: [B, Skv] i32 (0 = masked — supports non-prefix validity, e.g.
+    k_valid: [B, 1, Skv] i32 (0 = masked — supports non-prefix validity, e.g.
     PreTTR's padded-query + padded-doc two-prefix pattern; ``lengths`` stays
     the tile-skip bound and must cover every valid index).
     ``k_scales``/``v_scales`` (optional, both or neither): [B, Skv, 1] fp32
@@ -147,7 +149,7 @@ def flash_attention_pallas(q, k, v, lengths, k_valid, *, causal: bool,
         ]
         operands += [k_scales, v_scales]
     in_specs += [
-        pl.BlockSpec((1, block_k), lambda b, h, iq, ik, L: (b, ik)),
+        pl.BlockSpec((1, 1, block_k), lambda b, h, iq, ik, L: (b, 0, ik)),
     ]
     operands += [k_valid]
     return pl.pallas_call(

@@ -8,10 +8,7 @@ import jax.numpy as jnp
 
 from repro.kernels.masking import last_valid_lengths
 from repro.kernels.split_attention.kernel import flash_attention_pallas
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+from repro.kernels.tpu import interpret_mode, sublane
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -35,7 +32,7 @@ def split_flash_attention(q, k, v, lengths=None, k_valid=None,
     tail is masked and sliced off the output.
     """
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = interpret_mode()
     b, hq, sq, d = q.shape
     skv = k.shape[2]
     if lengths is None:
@@ -43,7 +40,7 @@ def split_flash_attention(q, k, v, lengths=None, k_valid=None,
                    else last_valid_lengths(k_valid, skv))
     if k_valid is None:
         k_valid = jnp.ones((b, skv), jnp.int32)
-    bq = min(block_q, max(8, sq))
+    bq = min(block_q, max(sublane(q.dtype), sq))
     bk = min(block_k, max(8, skv))
     pad_q = (-sq) % bq
     pad_k = (-skv) % bk
@@ -52,7 +49,7 @@ def split_flash_attention(q, k, v, lengths=None, k_valid=None,
     if pad_k:
         k = jnp.pad(k, ((0, 0), (0, 0), (0, pad_k), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, 0), (0, pad_k), (0, 0)))
-        k_valid = jnp.pad(k_valid.astype(jnp.int32), ((0, 0), (0, pad_k)))
+    k_valid = jnp.pad(k_valid.astype(jnp.int32), ((0, 0), (0, pad_k)))
     if k_scales is not None:
         k_scales = k_scales.astype(jnp.float32)
         v_scales = v_scales.astype(jnp.float32)
@@ -62,7 +59,7 @@ def split_flash_attention(q, k, v, lengths=None, k_valid=None,
         k_scales = k_scales[..., None]      # [B, Skv, 1] — row-broadcast
         v_scales = v_scales[..., None]
     out = flash_attention_pallas(q, k, v, lengths.astype(jnp.int32),
-                                 k_valid.astype(jnp.int32),
+                                 k_valid[:, None, :],
                                  causal=causal, window=window,
                                  seg_boundary=seg_boundary,
                                  block_q=bq, block_k=bk, interpret=interpret,

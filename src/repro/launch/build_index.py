@@ -17,10 +17,12 @@ then serve it without rebuilding::
 
 The corpus, config and parameter seeds match ``launch/serve.py`` exactly,
 so an index built here bit-matches the one ``serve`` would build inline
-(pass the same ``--l`` / ``--compress-dim`` / ``--n-docs``).
+(pass the same ``--config`` / ``--l`` / ``--compress-dim`` / ``--n-docs``).
+``--config base`` builds at the paper's BERT-base widths (seeded random
+init; default l=6, e=256).
 
-``--data-parallel`` shards each encode batch over every visible jax device
-(a ``("data",)`` mesh) — under ``XLA_FLAGS=--xla_force_host_platform_
+``--data-parallel`` gives every visible jax device ``--batch`` rows per
+encode step (a ``("data",)`` mesh) — under ``XLA_FLAGS=--xla_force_host_platform_
 device_count=8`` this exercises the same data-parallel path a TPU slice
 uses, and the written shards are doc-for-doc identical to the single-host
 build.  ``--distill-steps`` pre-trains the compression layer with the
@@ -70,26 +72,31 @@ def distill_compressor(params, cfg, world, steps: int, seed: int = 0,
 
 
 def main() -> None:
-    from repro.configs.prettr_bert import smoke_config
+    from repro.configs.prettr_bert import CONFIGS, config
     from repro.core.prettr import init_prettr
     from repro.data.synthetic_ir import SyntheticIRWorld
     from repro.index import IndexBuilder, TermRepIndex, available_codecs, \
         verify_index
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.models.backend import impls_for
 
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--out", default="results/prettr_index",
                     help="index directory to create")
-    ap.add_argument("--l", type=int, default=2)
-    ap.add_argument("--compress-dim", type=int, default=16)
+    ap.add_argument("--config", default="smoke", choices=sorted(CONFIGS),
+                    help="model widths: smoke (4L d=64) or base (the "
+                         "paper's BERT-base ranker, seeded random init)")
+    ap.add_argument("--l", type=int, default=None,
+                    help="join layer (default: the config's)")
+    ap.add_argument("--compress-dim", type=int, default=None,
+                    help="compression e (default: the config's)")
     ap.add_argument("--n-docs", type=int, default=512)
     ap.add_argument("--codec", default="fp16", choices=available_codecs())
     ap.add_argument("--shards", type=int, default=1,
                     help="number of shard-NNNNN/ output directories")
     ap.add_argument("--batch", type=int, default=64,
-                    help="fixed encode batch shape (rounded up to a "
-                         "multiple of the device count under "
-                         "--data-parallel)")
+                    help="fixed encode batch shape (rows per device "
+                         "under --data-parallel)")
     ap.add_argument("--backend", default="blocked",
                     choices=["plain", "blocked", "pallas"])
     ap.add_argument("--store-layer-kv", action="store_true",
@@ -121,9 +128,10 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    enable_compile_cache()
     attn_impl, compress_impl = impls_for(args.backend)
-    cfg = smoke_config(l=args.l, compress_dim=args.compress_dim,
-                       attn_impl=attn_impl, compress_impl=compress_impl)
+    cfg = config(args.config, l=args.l, compress_dim=args.compress_dim,
+                 attn_impl=attn_impl, compress_impl=compress_impl)
     world = SyntheticIRWorld(n_docs=args.n_docs,
                              vocab_size=cfg.backbone.vocab_size,
                              doc_len=cfg.max_doc_len - 2, seed=args.seed)

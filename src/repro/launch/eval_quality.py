@@ -61,6 +61,7 @@ def main() -> None:
     from repro.data.synthetic_ir import SyntheticIRWorld
     from repro.eval.cascade import run_cascade
     from repro.index import available_codecs
+    from repro.launch.compile_cache import enable_compile_cache
 
     ap = argparse.ArgumentParser(
         description="end-to-end cascade quality evaluation")
@@ -103,6 +104,7 @@ def main() -> None:
                     help="also dump metrics + metadata as JSON")
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = smoke_config(l=args.l, compress_dim=args.compress_dim)
     world = SyntheticIRWorld(n_docs=args.n_docs, n_queries=args.n_queries,
                              vocab_size=cfg.backbone.vocab_size,

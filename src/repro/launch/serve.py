@@ -16,11 +16,18 @@ loop to the ``RankingService`` request/response API: ``--concurrency N``
 queries are admitted at a time, their candidates are packed into fixed
 cross-query micro-batches while the prefetcher overlaps index reads with
 device compute, and throughput is reported as QPS with p50/p99 request
-latency.
+latency.  A degraded response with no ``FaultPlan`` installed is a failure:
+the run exits non-zero.
+
+``--config base`` serves the paper's ranker at its published widths
+(``configs/prettr_bert.full_config``: 12 layers, d=768, 32 + 480 tokens,
+default l=6, e=256) from a seeded random init; ``smoke`` (the default) is
+the 4-layer d=64 toy.
 """
 from __future__ import annotations
 
 import argparse
+import sys
 import time
 
 import numpy as np
@@ -29,16 +36,22 @@ import jax
 
 
 def main() -> None:
-    from repro.configs.prettr_bert import smoke_config
+    from repro.configs.prettr_bert import CONFIGS, config
     from repro.core.prettr import init_prettr
     from repro.data.synthetic_ir import (SyntheticIRWorld, pack_query,
                                          precision_at_k)
     from repro.index import IndexBuilder, TermRepIndex, available_codecs
-    from repro.serving import Reranker, RankingService, RankRequest
+    from repro.launch.compile_cache import enable_compile_cache
+    from repro.serving import Reranker, RankingService, RankRequest, faults
 
     ap = argparse.ArgumentParser()
-    ap.add_argument("--l", type=int, default=2)
-    ap.add_argument("--compress-dim", type=int, default=16)
+    ap.add_argument("--config", default="smoke", choices=sorted(CONFIGS),
+                    help="model widths: smoke (4L d=64) or base (the "
+                         "paper's BERT-base ranker, seeded random init)")
+    ap.add_argument("--l", type=int, default=None,
+                    help="join layer (default: the config's)")
+    ap.add_argument("--compress-dim", type=int, default=None,
+                    help="compression e (default: the config's)")
     ap.add_argument("--n-docs", type=int, default=512)
     ap.add_argument("--n-queries", type=int, default=16)
     ap.add_argument("--candidates", type=int, default=64)
@@ -68,9 +81,9 @@ def main() -> None:
                     help="--service: serve through the scale-out "
                          "RankingRouter with N ShardWorkers (shard-affinity "
                          "candidate routing over the doc table; each worker "
-                         "pinned to its own jax device when enough exist, "
-                         "with its own --doc-cache-mb budget); 0 = "
-                         "single-process RankingService")
+                         "pinned to its own jax device — on a TPU there "
+                         "must be N — with its own --doc-cache-mb budget); "
+                         "0 = single-process RankingService")
     ap.add_argument("--store-layer-kv", action="store_true",
                     help="store the join layer's doc-side K/V streams in "
                          "the built index (fused join skips the layer-l "
@@ -106,9 +119,10 @@ def main() -> None:
     args = ap.parse_args()
 
     from repro.models.backend import impls_for
+    enable_compile_cache()
     attn_impl, compress_impl = impls_for(args.backend)
-    cfg = smoke_config(l=args.l, compress_dim=args.compress_dim,
-                       attn_impl=attn_impl, compress_impl=compress_impl)
+    cfg = config(args.config, l=args.l, compress_dim=args.compress_dim,
+                 attn_impl=attn_impl, compress_impl=compress_impl)
     world = SyntheticIRWorld(n_docs=args.n_docs, n_queries=args.n_queries,
                              vocab_size=cfg.backbone.vocab_size,
                              doc_len=cfg.max_doc_len - 2, seed=0)
@@ -153,12 +167,8 @@ def main() -> None:
     if args.service:
         if args.serving_shards > 0:
             from repro.serving import RankingRouter
-            # pin one worker per device when the host has enough (forced
-            # host devices count); otherwise share the default device —
-            # same scores either way
-            devs = jax.devices()
-            devices = (devs[:args.serving_shards]
-                       if len(devs) >= args.serving_shards else None)
+            from repro.serving.sharded import worker_devices
+            devices = worker_devices(args.serving_shards)
             svc = RankingRouter(params, cfg, idx,
                                 n_shards=args.serving_shards,
                                 devices=devices,
@@ -233,6 +243,10 @@ def main() -> None:
               f"h2d={s.h2d_bytes / 2**20:.2f}MiB "
               f"doc_hbm={s.doc_hbm_bytes / 2**20:.2f}MiB{cache_note}"
               f"{fault_note} | P@20={np.mean(p20):.3f}")
+        if n_degraded and not faults.active():
+            print(f"[serve] FAILED: {n_degraded} degraded responses with no "
+                  f"fault plan installed", file=sys.stderr)
+            sys.exit(1)
         return
 
     rr = Reranker(params, cfg, idx, micro_batch=args.micro_batch)

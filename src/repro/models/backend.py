@@ -39,7 +39,7 @@ Kinds and their call contracts (all arrays in **model layout**):
   ``kd``/``vd`` as raw int8 codec payload dequantized on the fly (the
   reference impls widen before the concat, the pallas impl dequantizes
   in-register inside the KV tile loop); ``paged`` (an object with
-  ``k``/``v`` ``[P, page, Hkv, D]`` pools, ``page_table`` ``[B, nP]``,
+  ``k``/``v`` ``[P, Hkv, page, D]`` pools, ``page_table`` ``[B, nP]``,
   ``valid`` ``[P, page]``, optional ``k_scale``/``v_scale``
   ``[P, page, 1]`` — ``repro.core.prettr.PagedDocKV``) replaces ``kd``/
   ``vd`` entirely with the device doc cache's token-page pools: the
@@ -68,9 +68,9 @@ joint PreTTR forward, ``-1`` for single-segment ranges).  Mask positions
 are token indices, which matches every caller in this repo (sequences are
 ``arange``-positioned wherever causal/window/split masks are active).
 
-Off-TPU the kernel wrappers automatically fall back to Pallas interpret
-mode (``interpret=None`` -> interpret unless ``jax.default_backend() ==
-"tpu"``), so every backend runs — and is tested — on CPU.
+Off-TPU the kernel wrappers run in Pallas interpret mode
+(``interpret=None`` -> ``repro.kernels.tpu.interpret_mode()``), so every
+backend runs — and is tested — on CPU.
 """
 from __future__ import annotations
 
@@ -81,7 +81,8 @@ import jax.numpy as jnp
 from repro.kernels.decode_attention import flash_decode_attention
 from repro.kernels.fused_compress import fused_compress, fused_decompress
 from repro.kernels.join_attention import (join_flash_attention,
-                                          join_flash_attention_paged)
+                                          join_flash_attention_paged,
+                                          kv_pages_to_dense, pages_to_dense)
 from repro.kernels.split_attention import split_flash_attention
 from repro.models import layers as L
 
@@ -292,12 +293,6 @@ def _concat_join_operands(q, kq, vq, kd, vd, kq_valid, kd_valid):
     return k, v, k_valid
 
 
-def _pages_to_rows(pool, page_table):
-    """[P, page, ...] pool + [B, nP] table -> [B, nP * page, ...] rows."""
-    g = pool[page_table]
-    return g.reshape((g.shape[0], g.shape[1] * g.shape[2]) + g.shape[3:])
-
-
 def _densify_paged(paged, kd_valid):
     """Reference-impl form of the paged doc segment: gather the cache's
     token pages into dense ``[B, Ld, Hkv, D]`` rows (inside the caller's
@@ -305,12 +300,12 @@ def _densify_paged(paged, kd_valid):
     exactly the shapes the slot-cache path fed them — which is what keeps
     paged scores bit-exact vs the slot cache on float KV."""
     ld = kd_valid.shape[1] if kd_valid is not None else None
-    kd = _pages_to_rows(paged.k, paged.page_table)[:, :ld]
-    vd = _pages_to_rows(paged.v, paged.page_table)[:, :ld]
+    kd = kv_pages_to_dense(paged.k, paged.page_table)[:, :ld]
+    vd = kv_pages_to_dense(paged.v, paged.page_table)[:, :ld]
     kd_scale = vd_scale = None
     if paged.k_scale is not None:
-        kd_scale = _pages_to_rows(paged.k_scale, paged.page_table)[:, :ld, 0]
-        vd_scale = _pages_to_rows(paged.v_scale, paged.page_table)[:, :ld, 0]
+        kd_scale = pages_to_dense(paged.k_scale, paged.page_table)[:, :ld, 0]
+        vd_scale = pages_to_dense(paged.v_scale, paged.page_table)[:, :ld, 0]
     return kd, vd, kd_scale, vd_scale
 
 
@@ -397,7 +392,7 @@ def _join_pallas(q, kq, vq, kd, vd, *, cfg, scale, q_valid=None,
     vqt = vq.transpose(0, 2, 1, 3)
     if paged is not None:
         # the kernel's doc-segment index maps walk the page table — the
-        # pools ([P, page, Hkv, D]) are already in kernel page layout and
+        # pools ([P, Hkv, page, D]) are already in kernel page layout and
         # no dense per-batch KV copy is materialized
         out = join_flash_attention_paged(
             qt, kqt, vqt, paged.k, paged.v, paged.page_table, paged.valid,

@@ -103,7 +103,8 @@ def sharded_lookup(table, flat_ids, mesh, *, capacity_factor: float = 4.0):
     Over-capacity ids (Zipf skew) fall back to row 0 with a zero mask —
     sized by ``capacity_factor`` over the uniform expectation.
     """
-    from repro.dist.compat import NamedSharding, P, shard_map
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from jax import shard_map
 
     n = flat_ids.shape[0]
     r, d = table.shape

@@ -243,8 +243,12 @@ def project_kv(p, x, cfg: TransformerConfig, *, positions, rope_base=None):
     b, s, _ = x.shape
     dh = cfg.dh
     cd = cfg.compute_dtype
-    k = (x @ p["wk"].astype(cd)).reshape(b, s, cfg.n_kv_heads, dh)
-    v = (x @ p["wv"].astype(cd)).reshape(b, s, cfg.n_kv_heads, dh)
+    # one matmul for K and V: alone, a narrow (GQA) K or V width picks a
+    # CPU dot kernel whose ulps depend on the row count, and the split
+    # join projects each segment where the concat oracle projects both
+    w = jnp.concatenate([p["wk"], p["wv"]], axis=1).astype(cd)
+    kv = (x @ w).reshape(b, s, 2, cfg.n_kv_heads, dh)
+    k, v = kv[:, :, 0], kv[:, :, 1]
     if cfg.qkv_bias:
         k = k + p["bk"].astype(cd).reshape(cfg.n_kv_heads, dh)
         v = v + p["bv"].astype(cd).reshape(cfg.n_kv_heads, dh)

@@ -13,7 +13,10 @@ the cache footprint is the narrow encoded payload: an int8 index holds
 ~4x more resident docs per MiB than the old decoded-float pools.
 
 Design: **token-page pools**, paged-attention style.  Each stream is one
-preallocated device tensor ``[n_pages, page_tokens, ...]``; an LRU map
+preallocated device tensor ``[n_pages, page_tokens, ...]`` — or, for
+the streams the caller names ``head_major`` (the layer-``l`` K/V), the
+``[n_pages, H, page_tokens, D]`` that the paged join kernel reads one
+``(page_tokens, D)`` tile at a time; an LRU map
 assigns each doc a list of ``ceil(len/page_tokens)`` pages, so short docs
 no longer pin whole max-length slots.  Batch assembly is a page-table
 gather (``pool[page_table]``) and miss insertion one scatter per stream —
@@ -55,15 +58,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.join_attention import kv_pages_to_dense, pages_to_dense
+
 
 @functools.partial(jax.jit, donate_argnums=0)
 def _scatter(pool, pages, rows):
     return pool.at[pages].set(rows)
-
-
-@jax.jit
-def _take(pool, pages):
-    return pool[pages]
 
 
 class DeviceDocCache:
@@ -72,7 +72,10 @@ class DeviceDocCache:
     ``capacity_bytes`` bounds device memory; the page count is derived
     from the per-page footprint of ``streams`` — a ``{name: (dtype,
     row_shape)}`` spec as produced by ``IndexReader.streams_spec()`` —
-    plus one validity byte per token.  ``page_tokens=None`` (default)
+    plus one validity byte per token.  ``head_major`` maps the names of
+    streams to pool as ``[n_pages, H, page_tokens, D]`` (the paged join
+    kernel's K/V layout) to their ``(H, D)``; the cache owns that layout
+    on insert and in :meth:`dense`.  ``page_tokens=None`` (default)
     gives whole-doc pages (slot behavior); smaller values pack variable
     -length docs tighter.  ``page_bucket=True`` lets :meth:`plan` shrink
     the page-table width to the batch's longest doc (bucketed to powers
@@ -85,6 +88,7 @@ class DeviceDocCache:
 
     def __init__(self, capacity_bytes: int, *, doc_len: int,
                  streams: dict, page_tokens: int | None = None,
+                 head_major: dict | None = None,
                  page_bucket: bool = False, min_slots: int = 2,
                  device=None):
         if page_tokens is None:
@@ -99,6 +103,13 @@ class DeviceDocCache:
         self._streams = {
             name: (np.dtype(dt), tuple(shape))
             for name, (dt, shape) in streams.items()}
+        self._head_major = {n: tuple(hd) for n, hd in
+                            (head_major or {}).items()}
+        self._take = jax.jit(self.dense, static_argnums=0)
+        for n, (h, d) in self._head_major.items():
+            if int(np.prod(self._streams[n][1])) != h * d:
+                raise ValueError(f"stream {n!r} rows {self._streams[n][1]} "
+                                 f"do not hold {h} heads of {d}")
         row_bytes = sum(
             dt.itemsize * int(np.prod(shape, dtype=np.int64))
             for dt, shape in self._streams.values()) + 1   # + valid byte
@@ -117,17 +128,19 @@ class DeviceDocCache:
                 f"micro_batch")
         self.capacity_pages = n_pages
         self.capacity = (n_pages - 2) // self.pages_per_doc  # docs, worst case
-        # pools are *committed* to ``device`` when one is given (scale-out
-        # serving pins each shard worker's cache to its own device; the
+        # pools — and every page table / staged row sent to them — are
+        # *committed* to ``device`` when one is given (scale-out serving
+        # pins each shard worker's cache to its own device; the
         # scatter/gather jits then follow the pool's placement) — None
         # keeps jax's default placement
+        self.device = device
+
         def _alloc(shape, dt):
-            z = jnp.zeros(shape, dt)
-            return jax.device_put(z, device) if device is not None else z
+            return self.put(jnp.zeros(shape, dt))
 
         self._pools = {
-            name: _alloc((n_pages, page_tokens) + shape, dt)
-            for name, (dt, shape) in self._streams.items()}
+            name: _alloc(self._pool_shape(n_pages, name), dt)
+            for name, (dt, _) in self._streams.items()}
         #: device per-page validity (int8 — the paged kernel's dval pool)
         self.valid_pool = _alloc((n_pages, page_tokens), jnp.int8)
         self._valid_np = np.zeros((n_pages, page_tokens), bool)
@@ -139,6 +152,26 @@ class DeviceDocCache:
         #: LRU entries examined by the most recent :meth:`plan` (pinned
         #: skips + evictions) — bounded by the resident count per call
         self.last_plan_scans = 0
+
+    def put(self, x):
+        """``x`` on the cache's device (jax's default placement when the
+        cache is unpinned)."""
+        return jax.device_put(x, self.device)
+
+    def _pool_shape(self, n_pages: int, name: str) -> tuple:
+        if name in self._head_major:
+            h, d = self._head_major[name]
+            return (n_pages, h, self.page_tokens, d)
+        return (n_pages, self.page_tokens) + self._streams[name][1]
+
+    def dense(self, name: str, pool, page_table):
+        """Densify stream ``name``'s pool through a page table (traceable:
+        the serving jits call it on :attr:`pools`) -> ``[B, W *
+        page_tokens, *row]`` — ``[B, W * page_tokens, H, D]`` for a
+        head-major stream."""
+        if name in self._head_major:
+            return kv_pages_to_dense(pool, page_table)
+        return pages_to_dense(pool, page_table)
 
     def __len__(self):
         return len(self._pages_of)
@@ -261,33 +294,33 @@ class DeviceDocCache:
         miss_pages = np.asarray(miss_pages, np.int32)
         m, w = miss_pages.shape
         flat = miss_pages.reshape(-1)
-        pages_dev = jnp.asarray(flat)
+        pages_dev = self.put(flat)
         for name, rows in parts.items():
             pool = self._pools[name]
-            rows = jnp.asarray(rows).astype(pool.dtype).reshape(
-                (m * w, self.page_tokens) + pool.shape[2:])
+            rows = self.put(rows).astype(pool.dtype)
+            if name in self._head_major:           # -> [M*W, H, page, D]
+                rows = jnp.swapaxes(rows.reshape(
+                    (m * w, self.page_tokens) + self._head_major[name]),
+                    1, 2)
+            rows = rows.reshape((m * w,) + pool.shape[1:])
             self._pools[name] = _scatter(pool, pages_dev, rows)
         valid = np.asarray(valid, bool).reshape(m * w, self.page_tokens)
         self.valid_pool = _scatter(self.valid_pool, pages_dev,
-                                   jnp.asarray(valid, jnp.int8))
+                                   self.put(valid.astype(np.int8)))
         keep = flat != self.SCRATCH_PAGE
         self._valid_np[flat[keep]] = valid[keep]
 
     def take(self, page_table):
         """Densify a planned batch: page-table gather per stream ->
         ``(parts, valid_np)`` with ``parts[name]`` shaped
-        ``[B, W * page_tokens, ...]``.
+        ``[B, W * page_tokens, *row_shape]``.
 
         The serving hot path skips this and indexes the :attr:`pools`
         directly inside jitted device code (its pool-fused assemble/score
         dispatches); ``take`` is the standalone accessor for tests."""
-        pt = jnp.asarray(np.asarray(page_table, np.int32))
-        b, w = page_table.shape
-        parts = {}
-        for name, pool in self._pools.items():
-            g = _take(pool, pt)
-            parts[name] = g.reshape((b, w * self.page_tokens)
-                                    + pool.shape[2:])
+        pt = self.put(np.asarray(page_table, np.int32))
+        parts = {name: self._take(name, pool, pt)
+                 for name, pool in self._pools.items()}
         return parts, self.valid_rows(page_table)
 
     @property

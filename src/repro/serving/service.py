@@ -43,6 +43,7 @@ changes throughput, never scores.
 from __future__ import annotations
 
 import dataclasses
+import logging
 import queue
 import threading
 import time
@@ -54,8 +55,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import prettr as P
+from repro.kernels.join_attention import pages_to_dense
 from repro.index.store import TermRepIndex
 from repro.serving import faults
+
+log = logging.getLogger(__name__)
 
 
 # ---------------------------------------------------------------------------
@@ -512,6 +516,7 @@ class BatchEngine:
             # inside the pool-fused scoring jit, so an int8 index keeps
             # ~4x more docs resident per MiB than decoded-float pools
             spec = dict(codec.streams(index.rep_dim))
+            head_major = {}
             if self.use_layer_kv:
                 kvs = getattr(index, "kv_streams_spec", None)
                 spec.update(kvs() if kvs else {
@@ -519,10 +524,15 @@ class BatchEngine:
                                 (index.kv_dim,)),
                     "layer_v": (np.dtype(index.layer_kv["dtype"]),
                                 (index.kv_dim,))})
+                # K/V pools take the paged join kernel's layout
+                # ([P, Hkv, page, Dh]: one (page, Dh) tile per head)
+                bb = cfg.backbone
+                head_major = {n: (bb.n_kv_heads, bb.dh)
+                              for n in ("layer_k", "layer_v")}
             self._cache_streams = list(spec)
-            self._doc_cache = DeviceDocCache(
+            cache = self._doc_cache = DeviceDocCache(
                 int(doc_cache_mb * 2**20), doc_len=cfg.max_doc_len,
-                streams=spec, page_tokens=page_tokens,
+                streams=spec, page_tokens=page_tokens, head_major=head_major,
                 page_bucket=page_bucket, min_slots=2 * self.micro_batch,
                 device=device)
             # pool-fused scoring, one `_join_pool` call per micro-batch and
@@ -538,33 +548,26 @@ class BatchEngine:
             # The raw int8 K/V bytes + scales pass through the seam
             # undecoded, so dequantization still happens inside the scoring
             # jit and `stats.n_decode_dispatch` stays 0.
-            page = self._doc_cache.page_tokens
             use_kv, kvq = self.use_layer_kv, self._kv_quant
             rep_streams = list(codec.streams(index.rep_dim))
 
-            def _dense(a, pt):
-                b, w = pt.shape
-                return a[pt].reshape((b, w * page) + a.shape[2:])
+            def _dense(pools, name, pt):
+                return cache.dense(name, pools[name], pt)
 
             def _pool_assemble(pools, vpool, pt):
-                dval = _dense(vpool, pt).astype(bool)
+                dval = pages_to_dense(vpool, pt).astype(bool)
                 if codec.decode_is_identity:
-                    x_d = _dense(pools["reps"], pt)
+                    x_d = _dense(pools, "reps", pt)
                 else:
                     x_d = codec.decode_group(
-                        "reps",
-                        {s: _dense(pools[s], pt) for s in rep_streams})
+                        "reps", {s: _dense(pools, s, pt) for s in rep_streams})
                 dkv = None
                 if use_kv:
-                    dkv = ((_dense(pools["layer_k"], pt),
-                            _dense(pools["layer_v"], pt),
-                            _dense(pools[kv_codec.scale_stream("layer_k")],
-                                   pt),
-                            _dense(pools[kv_codec.scale_stream("layer_v")],
-                                   pt))
-                           if kvq else
-                           (_dense(pools["layer_k"], pt),
-                            _dense(pools["layer_v"], pt)))
+                    names = ["layer_k", "layer_v"]
+                    if kvq:
+                        names += [kv_codec.scale_stream("layer_k"),
+                                  kv_codec.scale_stream("layer_v")]
+                    dkv = tuple(_dense(pools, n, pt) for n in names)
                 return x_d, dval, dkv
 
             def _dense_score(p, qr, qv, x_d, dval, dkv):
@@ -572,13 +575,12 @@ class BatchEngine:
                                         doc_kv=dkv, fused=fused)
 
             def _pool_score(p, qr, qv, pools, vpool, pt):
-                dval = _dense(vpool, pt).astype(bool)
+                dval = pages_to_dense(vpool, pt).astype(bool)
                 if codec.decode_is_identity:
-                    x_d = _dense(pools["reps"], pt)
+                    x_d = _dense(pools, "reps", pt)
                 else:
                     x_d = codec.decode_group(
-                        "reps",
-                        {s: _dense(pools[s], pt) for s in rep_streams})
+                        "reps", {s: _dense(pools, s, pt) for s in rep_streams})
                 dkv = P.PagedDocKV(
                     k=pools["layer_k"], v=pools["layer_v"],
                     valid=vpool, page_table=pt,
@@ -635,11 +637,17 @@ class BatchEngine:
             return None
         rows = [self._rows.popleft()
                 for _ in range(min(self.micro_batch, len(self._rows)))]
-        # pad to the fixed micro-batch shape (single jit cache entry);
-        # padding replicates the last real row, scores are discarded
-        pad_doc = rows[-1][2]
-        rows += [(None, -1, pad_doc)] * (self.micro_batch - len(rows))
-        return _Plan(rows=rows)
+        return self._padded_plan(rows)
+
+    def _padded_plan(self, rows: list, depth: int = 0) -> _Plan:
+        """Pad real rows to the fixed micro-batch shape: every batch,
+        redispatched halves included, runs the one compiled program, and
+        XLA's output differs at the ulp across batch shapes (not row
+        positions), so a row scores the same bits in any batch.  Padding
+        replicates the last real row; its scores are discarded."""
+        pad = (None, -1, rows[-1][2])
+        return _Plan(rows=rows + [pad] * (self.micro_batch - len(rows)),
+                     depth=depth)
 
     def _stage(self, plan: _Plan):
         """Host-side staging of one planned batch: index gather (the
@@ -799,7 +807,12 @@ class BatchEngine:
         """Resolve an errored plan's real rows as *failed*: the row index
         lands on its state's ``failed_idx`` (the composer flags the
         response degraded), the score is ``-inf`` (sorts to the bottom),
-        and the state still completes — no co-packed state is lost."""
+        and the state still completes — no co-packed state is lost.  The
+        error is logged with its traceback: a failed batch is never
+        silent, even where the degraded response is the right answer."""
+        log.warning("micro-batch of %d rows failed (engine %s)",
+                    sum(s is not None for s, _, _ in plan.rows),
+                    self.fault_tag, exc_info=err)
         for s, ci, _ in plan.rows:
             if s is None:
                 continue
@@ -846,7 +859,7 @@ class BatchEngine:
             self.stats.n_doc_cache_hit += (payload["n_rows"]
                                            - payload["n_miss_rows"])
             self.stats.resident_docs = cache.resident_docs
-            pt = jnp.asarray(payload["page_table"])
+            pt = cache.put(payload["page_table"])
             # doc-side bytes the join pulls from device memory: one page
             # gather per page-table entry (validity byte included)
             self.stats.doc_hbm_bytes += (payload["page_table"].size
@@ -883,7 +896,8 @@ class BatchEngine:
         uniq = {id(s): s for s in states}
         deadline = self.policy.batch_deadline(
             [s.deadline_s for s in uniq.values()])
-        if self.policy.should_redispatch(dt, deadline, len(rows), plan.depth):
+        if self.policy.should_redispatch(dt, deadline, len(states),
+                                         plan.depth):
             # the overshooting attempt's scores are discarded — only the
             # re-dispatched halves (whose results are returned) may count
             # toward the Table-5 split
@@ -891,9 +905,9 @@ class BatchEngine:
             self.stats.discarded_s += dt + load_dt
             for s in uniq.values():
                 s.stats.n_redispatch += 1
-            halves = [_Plan(rows=h, depth=plan.depth + 1)
-                      for h in self.policy.split(rows)
-                      if any(r[0] is not None for r in h)]
+            real = [r for r in rows if r[0] is not None]
+            halves = [self._padded_plan(h, plan.depth + 1)
+                      for h in self.policy.split(real) if h]
             self._replans.extendleft(reversed(halves))
             return
 
@@ -1123,7 +1137,8 @@ class RankingService:
         dt = time.perf_counter() - t0
         state.stats.query_encode_s = dt
         self.stats.query_encode_s += dt
-        state.q_valid_j = jnp.asarray(req.q_valid)
+        state.q_valid_j = jax.device_put(np.asarray(req.q_valid),
+                                         self.engine.device)
         self.engine.enqueue(state)
         self._queued += 1
         return rid

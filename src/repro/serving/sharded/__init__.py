@@ -23,7 +23,9 @@ instead of dying: per-worker :class:`WorkerHealth` state machines,
 timed drains, bounded retry, full-index failover, and degraded
 responses (see the router module docstring).
 """
-from repro.serving.sharded.router import RankingRouter, WorkerHealth
+from repro.serving.sharded.router import (RankingRouter, WorkerHealth,
+                                          worker_devices)
 from repro.serving.sharded.worker import ShardTask, ShardWorker
 
-__all__ = ["RankingRouter", "ShardTask", "ShardWorker", "WorkerHealth"]
+__all__ = ["RankingRouter", "ShardTask", "ShardWorker", "WorkerHealth",
+           "worker_devices"]

@@ -72,6 +72,23 @@ from repro.serving.service import (BatchEngine, RankRequest, RankResponse,
 from repro.serving.sharded.worker import ShardTask, ShardWorker
 
 
+def worker_devices(n_shards: int):
+    """One device per shard worker: the first ``n_shards`` devices.
+
+    On a TPU, fewer devices than shards is an error — workers sharing a
+    chip would serve (and measure) a different system.  Off the chip,
+    without forced host devices, the workers share the default device
+    (``None``): same scores, which is what the CPU tests check."""
+    devs = jax.devices()
+    if len(devs) >= n_shards:
+        return devs[:n_shards]
+    if devs[0].platform == "tpu":
+        raise ValueError(
+            f"{n_shards} serving shards need {n_shards} devices; this "
+            f"host has {len(devs)} {devs[0].device_kind}")
+    return None
+
+
 class WorkerHealth:
     """Per-worker health state machine.
 

@@ -4,24 +4,17 @@ multi-device meshes are modeled with ``AbstractMesh`` (no devices touched);
 the numerics of sharded execution live in test_distributed.py."""
 import jax
 import jax.numpy as jnp
-import pytest
+from jax.sharding import AbstractMesh
 from jax.sharding import PartitionSpec as P
 
 from repro.dist import (ShardingRules, current_rules, default_rules,
                         divisible_spec, install_rules, maybe_shard,
                         replicated_serving_rules)
 
-try:
-    from jax.sharding import AbstractMesh
-except ImportError:  # pragma: no cover - older jax
-    AbstractMesh = None
-
-pytestmark = pytest.mark.skipif(
-    AbstractMesh is None, reason="jax.sharding.AbstractMesh unavailable")
-
 
 def _mesh(shape=(("data", 4), ("model", 2))):
-    return AbstractMesh(tuple(shape))
+    names, sizes = zip(*shape)
+    return AbstractMesh(sizes, names)
 
 
 # ---------------------------------------------------------------------------

@@ -34,7 +34,8 @@ def test_sharded_embedding_lookup_matches_take():
     from repro.dist.context import install_rules
     from repro.models.recsys.embedding import sharded_lookup
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    from repro.dist import make_mesh
+    mesh = make_mesh((4, 2), ("data", "model"))
     rules = default_rules(mesh)
     table = jax.random.normal(jax.random.PRNGKey(0), (64, 16))
     ids = jax.random.randint(jax.random.PRNGKey(1), (32,), 0, 64)
@@ -60,7 +61,8 @@ def test_moe_grouped_matches_single_device():
     from repro.dist.context import install_rules
     from repro.models.moe import init_moe, moe_ffn
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    from repro.dist import make_mesh
+    mesh = make_mesh((4, 2), ("data", "model"))
     rules = default_rules(mesh)
     p, _ = init_moe(jax.random.PRNGKey(0), 32, 64, 8, jnp.float32)
     x = jax.random.normal(jax.random.PRNGKey(1), (64, 32))
@@ -96,7 +98,8 @@ def test_sharded_transformer_matches_single_device():
     toks = jax.random.randint(jax.random.PRNGKey(1), (8, 32), 0, 256)
     ref = causal_lm_loss(params, cfg, toks[:, :-1], toks[:, 1:])
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    from repro.dist import make_mesh
+    mesh = make_mesh((4, 2), ("data", "model"))
     rules = default_rules(mesh)
     shapes, ax = eval_params(lambda k: init_params(k, cfg))
     specs = attach_shardings(shapes, ax, rules)
@@ -117,10 +120,11 @@ def test_compressed_psum_pod_axis():
     _run("""
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import PartitionSpec as P
-    from repro.dist.compat import shard_map
+    from jax import shard_map
     from repro.optim.compression import compressed_psum, init_error_feedback
 
-    mesh = jax.make_mesh((8,), ("pod",))
+    from repro.dist import make_mesh
+    mesh = make_mesh((8,), ("pod",))
     grads = {"w": jax.random.normal(jax.random.PRNGKey(0), (8, 64))}
     fb = {"w": jnp.zeros((1, 64))}
 

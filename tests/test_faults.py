@@ -617,3 +617,34 @@ def test_pinned_worker_failover_subprocess():
     assert out.returncode == 0, \
         f"STDOUT:\n{out.stdout}\nSTDERR:\n{out.stderr}"
     assert "OK pinned failover" in out.stdout
+
+
+@pytest.mark.parametrize("plan", [False, True])
+def test_serve_cli_fails_on_unplanned_degraded(monkeypatch, tmp_path, plan):
+    """`repro.launch.serve` exits 1 when a response comes back degraded
+    with no fault plan installed (a broken kernel must not look like a
+    served run); under an installed plan degraded responses are the
+    expected outcome and the run completes."""
+    import repro.serving as S
+    from repro.launch import compile_cache, serve
+
+    class AlwaysDegraded(S.RankingService):
+        def drain(self):
+            out = super().drain()
+            for r in out:
+                r.degraded = True
+            return out
+
+    monkeypatch.setattr(S, "RankingService", AlwaysDegraded)
+    monkeypatch.setattr(compile_cache, "enable_compile_cache", lambda: "")
+    monkeypatch.setattr(sys, "argv", [
+        "serve", "--service", "--n-docs", "16", "--n-queries", "2",
+        "--candidates", "8", "--micro-batch", "8",
+        "--index-dir", str(tmp_path / "idx")])
+    if plan:
+        with FaultPlan([]):
+            serve.main()
+        return
+    with pytest.raises(SystemExit) as exc:
+        serve.main()
+    assert exc.value.code == 1

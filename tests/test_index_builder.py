@@ -350,3 +350,45 @@ def test_sharded_build_matches_single_host():
     assert out.returncode == 0, \
         f"STDOUT:\n{out.stdout}\nSTDERR:\n{out.stderr}"
     assert "OK sharded build" in out.stdout
+
+
+def test_data_parallel_batch_is_per_device():
+    """Under a mesh, ``batch_size`` rows go to every device: the manifest
+    records that per-device shape, and ``verify_index`` replays it on one
+    device byte-for-byte."""
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
+    env["PYTHONPATH"] = os.path.join(ROOT, "src")
+    env["JAX_PLATFORMS"] = "cpu"
+    snippet = """
+    import tempfile
+    import numpy as np
+    import jax, jax.numpy as jnp
+    from repro.core.prettr import PreTTRConfig, make_backbone, init_prettr
+    from repro.index import IndexBuilder, TermRepIndex, verify_index
+
+    bb = make_backbone(n_layers=2, d_model=32, n_heads=2, d_ff=64,
+                       vocab_size=128, l=1, max_len=24,
+                       compute_dtype=jnp.float32, block_kv=8)
+    cfg = PreTTRConfig(backbone=bb, l=1, max_query_len=8, max_doc_len=16,
+                       compress_dim=8)
+    params, _ = init_prettr(jax.random.PRNGKey(0), cfg)
+    rng = np.random.default_rng(1)
+    docs = [rng.integers(5, 128, size=rng.integers(4, 15))
+            for _ in range(11)]
+    with tempfile.TemporaryDirectory() as d:
+        b = IndexBuilder(d, cfg, params, codec="int8", batch_size=4,
+                         mesh=jax.make_mesh((2,), ("data",)))
+        assert b.batch_size == 4
+        b.build(docs)
+        idx = TermRepIndex.open(d)
+        assert idx.encode_batch == 4 and len(idx) == 11
+        assert verify_index(idx, cfg, params, docs, sample=11) == 11
+    print("OK per-device batch")
+    """
+    out = subprocess.run([sys.executable, "-c", textwrap.dedent(snippet)],
+                         capture_output=True, text=True, env=env,
+                         timeout=420)
+    assert out.returncode == 0, \
+        f"STDOUT:\n{out.stdout}\nSTDERR:\n{out.stderr}"
+    assert "OK per-device batch" in out.stdout

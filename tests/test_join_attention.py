@@ -130,13 +130,17 @@ def test_join_kernel_int8_in_kernel_dequant_bit_exact():
 
 def _paginate(kd, vd, kdv, page, kd_s=None, vd_s=None):
     """Pack dense [B, Hkv, Ld, D] doc K/V into cache-layout page pools
-    ([P, page, Hkv, D]) with page 0 reserved all-zero; rows keep all their
+    ([P, Hkv, page, D]) with page 0 reserved all-zero; rows keep all their
     pages (dense table) so the paged kernel sees the same assembled
     positions as the dense kernel."""
     b, hkv, ld, d = kd.shape
     n_p = ld // page
-    kd_r = np.moveaxis(np.asarray(kd), 1, 2).reshape(b * n_p, page, hkv, d)
-    vd_r = np.moveaxis(np.asarray(vd), 1, 2).reshape(b * n_p, page, hkv, d)
+
+    def _pages(x):                  # [B, Hkv, nP*page, D] -> [B*nP, Hkv, page, D]
+        x = np.asarray(x).reshape(b, hkv, n_p, page, d)
+        return np.moveaxis(x, 2, 1).reshape(b * n_p, hkv, page, d)
+
+    kd_r, vd_r = _pages(kd), _pages(vd)
     zeros = np.zeros_like(kd_r[:1])
     kd_pages = jnp.asarray(np.concatenate([zeros, kd_r]))
     vd_pages = jnp.asarray(np.concatenate([zeros, vd_r]))

@@ -161,3 +161,62 @@ def test_embedding_bag_sweep(rows, dim, nb, nnz, mode):
     ref = embedding_bag_ref(table, ids, w, mode=mode)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-5,
                                atol=1e-5)
+
+
+def test_f16_bits_widen_every_pattern():
+    """The in-register fp16 -> f32 widening (the TPU's Pallas compiler has
+    no fp16) is exact for all 65536 bit patterns."""
+    from repro.kernels.tpu import f16_bits_to_f32
+    h = np.arange(1 << 16, dtype=np.uint16)
+    want = h.view(np.float16).astype(np.float32)
+    got = np.asarray(f16_bits_to_f32(jnp.asarray(h)))
+    nan = np.isnan(want)
+    np.testing.assert_array_equal(np.isnan(got), nan)
+    np.testing.assert_array_equal(got[~nan].view(np.uint32),
+                                  want[~nan].view(np.uint32))
+
+
+def test_f32_to_f16_bits_rounds_like_convert():
+    """The in-register f32 -> fp16 narrowing rounds to nearest-even like
+    XLA's convert: every fp16 value, its float neighbours, the halfway
+    points between fp16 values, overflow and random magnitudes."""
+    from repro.kernels.tpu import f32_to_f16_bits
+    halves = np.arange(1 << 16, dtype=np.uint16).view(np.float16)
+    vals = halves[np.isfinite(halves)].astype(np.float32)
+    vals = np.sort(np.unique(vals))
+    mids = ((vals[:-1].astype(np.float64) + vals[1:]) / 2).astype(np.float32)
+    rng = np.random.default_rng(0)
+    x = np.concatenate([
+        vals, mids, np.nextafter(vals, np.float32(np.inf)),
+        np.nextafter(vals, np.float32(-np.inf)),
+        np.float32([65519.99, 65520.0, 65536.0, 1e30, -1e30, np.inf,
+                    -np.inf, -0.0]),
+        *(rng.standard_normal(20000).astype(np.float32) * s
+          for s in (1e-7, 1e-5, 1e-2, 1.0, 1e3, 6e4))])
+    with np.errstate(over="ignore"):
+        want = x.astype(np.float16).view(np.uint16)
+    got = np.asarray(f32_to_f16_bits(jnp.asarray(x))).astype(np.uint16)
+    np.testing.assert_array_equal(got, want)
+    assert int(f32_to_f16_bits(jnp.float32(np.nan))) & 0x7FFF == 0x7E00
+
+
+@pytest.mark.parametrize("t,e,d", [(40, 16, 64), (300, 32, 128)])
+def test_fused_compress_fp16_bits_match_convert(t, e, d):
+    """fp16 store and fp16 load cross the kernel as uint16 bits: the
+    stored bytes equal the f32 kernel output converted by XLA, and
+    decompressing them equals decompressing their f32 widening."""
+    ks = jax.random.split(jax.random.PRNGKey(7), 3)
+    x = jax.random.normal(ks[0], (t, d))
+    w = jax.random.normal(ks[1], (d, e)) / np.sqrt(d)
+    b = jax.random.normal(ks[2], (e,)) * 0.1
+    got = fused_compress(x, w, b, out_dtype=jnp.float16)
+    assert got.dtype == jnp.float16
+    want = fused_compress(x, w, b, out_dtype=jnp.float32).astype(jnp.float16)
+    np.testing.assert_array_equal(np.asarray(got).view(np.uint16),
+                                  np.asarray(want).view(np.uint16))
+    wd = jax.random.normal(ks[0], (e, d)) / np.sqrt(e)
+    bd, g, beta = jnp.zeros(d), jnp.ones(d), jnp.zeros(d)
+    out = fused_decompress(got, wd, bd, g, beta, out_dtype=jnp.float32)
+    ref = fused_decompress(got.astype(jnp.float32), wd, bd, g, beta,
+                           out_dtype=jnp.float32)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))

@@ -87,6 +87,27 @@ def test_deadline_redispatch_under_policy(world):
     np.testing.assert_array_equal(resp.scores, ref.scores)
 
 
+def test_redispatch_halves_keep_batch_shape(world):
+    """Redispatched halves are padded back to the micro-batch shape, so
+    every attempt runs the one compiled scoring program."""
+    cfg, params, path, queries, qv, cands = world
+    idx = TermRepIndex.open(path)
+    svc = RankingService(params, cfg, idx, micro_batch=8,
+                         policy=SchedulerPolicy(max_split_depth=2))
+    eng = svc.engine
+    score, rows = eng._score_batch, []
+
+    def spy(qr, qv_, payload):
+        rows.append(qr.shape[0])
+        return score(qr, qv_, payload)
+
+    eng._score_batch = spy
+    resp = svc.rank(queries[0], qv, list(range(5)), deadline_s=0.0)
+    assert resp.stats.n_redispatch == 3
+    assert rows == [8] * 7            # 1 + 2 halves + 4 quarters
+    assert sorted(resp.doc_ids) == list(range(5))
+
+
 def test_policy_split_depth_zero_disables_redispatch(world):
     cfg, params, path, queries, qv, cands = world
     idx = TermRepIndex.open(path)

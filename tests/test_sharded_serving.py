@@ -405,3 +405,22 @@ def test_pinned_workers_2_shards_bit_match():
 def test_pinned_workers_4_shards_bit_match():
     out = _run(_PINNED_SNIPPET.format(n_shards=4))
     assert "OK pinned 4" in out
+
+
+def test_worker_devices_pins_or_refuses(monkeypatch):
+    """One device per worker; too few devices share the default one off
+    the chip, and are an error on a TPU."""
+    from repro.serving.sharded import worker_devices
+
+    class Dev:
+        def __init__(self, platform):
+            self.platform, self.device_kind = platform, f"{platform} dev"
+
+    cpus = [Dev("cpu"), Dev("cpu")]
+    monkeypatch.setattr(jax, "devices", lambda: cpus)
+    assert worker_devices(2) == cpus
+    assert worker_devices(1) == cpus[:1]
+    assert worker_devices(4) is None
+    monkeypatch.setattr(jax, "devices", lambda: [Dev("tpu")])
+    with pytest.raises(ValueError, match="need 4 devices"):
+        worker_devices(4)
