@@ -1,0 +1,9 @@
+"""Doc cache (``DeviceDocCache.plan``: LRU walk, page allocation and the
+page-table build, the program's ``prettr.plan`` span on the prefetch
+thread): ``ServiceStats.plan_s`` over its ``n_batches`` in the window, in
+ms per micro-batch.  A part of ``stage_ms_per_batch``."""
+
+
+def read(ctx):
+    s, n = ctx.stats.get("plan_s"), ctx.stats.get("n_batches")
+    return 1e3 * s / n if s is not None and n else None

@@ -250,19 +250,18 @@ def main() -> None:
         return
 
     rr = Reranker(params, cfg, idx, micro_batch=args.micro_batch)
-    lat, p20 = [], []
+    p20 = []
     for qi in range(world.n_queries):
         cands = list(world.candidates(qi, k=args.candidates))
         q, qv = pack_query(world.queries[qi], cfg.max_query_len)
-        ranked, scores, stats = rr.rerank(q, qv, cands)
-        lat.append(stats)
+        ranked, scores, _ = rr.rerank(q, qv, cands)
         p20.append(precision_at_k(world.qrels[qi][np.asarray(ranked)], 20))
-    # drop the jit-warmup query from latency stats
-    lat = lat[1:] if len(lat) > 1 else lat
-    qenc = np.mean([s.query_encode_s for s in lat])
-    load = np.mean([s.load_s for s in lat])
-    comb = np.mean([s.combine_s for s in lat])
-    print(f"[serve] {len(lat)} queries x {args.candidates} candidates | "
+        if qi == 0 and world.n_queries > 1:
+            rr.reset_stats()               # drop the jit-warmup query
+    s = rr.stats
+    n = max(1, s.n_requests)
+    qenc, load, comb = s.query_encode_s / n, s.load_s / n, s.combine_s / n
+    print(f"[serve] {n} queries x {args.candidates} candidates | "
           f"query={qenc*1e3:.1f}ms load={load*1e3:.1f}ms "
           f"combine={comb*1e3:.1f}ms total={(qenc+load+comb)*1e3:.1f}ms | "
           f"P@20={np.mean(p20):.3f}")

@@ -71,6 +71,15 @@ class Reranker:
     def micro_batch(self, value):
         self._service.micro_batch = value
 
+    @property
+    def stats(self):
+        """The private service's ``ServiceStats`` (per-batch phase clocks,
+        summed over every ``rerank`` since the last :meth:`reset_stats`)."""
+        return self._service.stats
+
+    def reset_stats(self) -> None:
+        self._service.reset_stats()
+
     def rerank(self, q_tokens: np.ndarray, q_valid: np.ndarray,
                doc_ids: Sequence[int]):
         """-> (doc_ids sorted by descending score, scores, stats)."""

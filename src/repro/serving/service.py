@@ -30,10 +30,13 @@ be composed twice: ``RankingService`` pairs one engine with the admission
 (pinned to its own device, with its own doc cache and prefetch thread)
 behind a :class:`~repro.serving.sharded.RankingRouter`.
 
-Per-request phase timings (:class:`RerankStats`) keep the Table-5 split:
+The Table-5 split is kept per micro-batch in :class:`ServiceStats`:
 ``query_encode_s`` (Query), ``load_s`` (index gather + H2D + packed q-rep
 assembly — overlapped with device compute, so phase sums can exceed wall
-clock), ``combine_s`` (Decompress + Combine on device).
+clock), ``combine_s`` (Decompress + Combine on device).  Each clock is a
+named span (``repro.serving.spans``) that also lands on the profiler's
+trace.  Per request, :class:`RerankStats` keeps its query encode and its
+wait in the row pool before its first micro-batch is staged.
 
 Equivalence invariant (tests/test_service.py): for any workload, the packed
 service returns per query exactly what a sequential ``Reranker.rerank``
@@ -47,7 +50,7 @@ import logging
 import queue
 import threading
 import time
-from collections import Counter, OrderedDict, deque
+from collections import OrderedDict, deque
 from typing import Callable, Sequence
 
 import jax
@@ -57,7 +60,8 @@ import numpy as np
 from repro.core import prettr as P
 from repro.kernels.join_attention import pages_to_dense
 from repro.index.store import TermRepIndex
-from repro.serving import faults
+from repro.serving import faults, spans
+from repro.serving.spans import span
 
 log = logging.getLogger(__name__)
 
@@ -83,18 +87,16 @@ class RankRequest:
 
 @dataclasses.dataclass
 class RerankStats:
-    """Per-request phase split matching paper Table 5 (Query / load+H2D /
-    Decompress+Combine).  For packed batches each request is attributed its
-    row-proportional share of the batch time."""
+    """Per-request clocks: ``query_encode_s``, the Table-5 Query phase
+    (0 on a query-rep cache hit), and ``queue_wait_s``, from the
+    request's enqueue to the start of staging of the first micro-batch
+    that holds one of its rows (under a router, the longest of its shard
+    tasks' waits).  Micro-batch phase times are per batch, shared by
+    every request packed into it: they live on :class:`ServiceStats`."""
     query_encode_s: float = 0.0
-    load_s: float = 0.0
-    combine_s: float = 0.0
+    queue_wait_s: float = 0.0
     n_docs: int = 0
     n_redispatch: int = 0
-
-    @property
-    def total_s(self):
-        return self.query_encode_s + self.load_s + self.combine_s
 
 
 @dataclasses.dataclass
@@ -165,8 +167,17 @@ class ServiceStats:
     n_degraded: int = 0
     n_shed: int = 0
     query_encode_s: float = 0.0
-    load_s: float = 0.0
-    combine_s: float = 0.0
+    load_s: float = 0.0                   # staging, per accepted batch
+    combine_s: float = 0.0                # scoring, per accepted batch
+    # parts of load_s (prefetch thread): doc-cache planning, index gather
+    plan_s: float = 0.0
+    gather_s: float = 0.0
+    # part of combine_s: scoring-step dispatch up to the scores' device_get
+    dispatch_s: float = 0.0
+    stage_wait_s: float = 0.0             # scoring thread waiting on staging
+    # request waits in the row pool before their first staged batch
+    queue_wait_s: float = 0.0
+    n_queue_waits: int = 0
     discarded_s: float = 0.0              # time spent on overshooting attempts
     wall_s: float = 0.0                   # total time inside drain()
 
@@ -277,7 +288,7 @@ class DeadlinePriorityPolicy(SchedulerPolicy):
 class _ReqState:
     __slots__ = ("req", "rid", "seq", "n", "priority", "deadline_s",
                  "q_reps", "q_valid_j", "scores", "n_done", "t_submit",
-                 "stats", "failed_idx", "error")
+                 "t_enqueue", "stats", "failed_idx", "error")
 
     def __init__(self, req: RankRequest, rid: str, seq: int,
                  deadline_s: float | None):
@@ -292,6 +303,7 @@ class _ReqState:
         self.scores = np.zeros(self.n, np.float32)
         self.n_done = 0
         self.t_submit = time.perf_counter()
+        self.t_enqueue = None             # set by BatchEngine.enqueue
         self.stats = RerankStats(n_docs=self.n)
         self.failed_idx: list[int] = []   # candidate rows a fault invalidated
         self.error: BaseException | None = None
@@ -305,7 +317,27 @@ class _Plan:
     depth: int = 0
 
 
+@dataclasses.dataclass
+class _StageClocks:
+    """Host clocks of one staged micro-batch, taken on the prefetch thread
+    and added to :class:`ServiceStats` on the scoring thread (no stats
+    field is written from two threads)."""
+    t_start: float
+    plan_s: float = 0.0
+    gather_s: float = 0.0
+    load_s: float = 0.0
+
+
 _STOP = object()
+
+
+def encode_query_jit(cfg: P.PreTTRConfig):
+    """The query-side encode (layers ``0..l``), jitted under a stable name:
+    its program shows on the device trace as ``jit__encode_query``."""
+    def _encode_query(p, t, v):
+        return P.encode_query(p, cfg, t, v)
+
+    return jax.jit(_encode_query)
 
 
 # ---------------------------------------------------------------------------
@@ -403,8 +435,9 @@ class BatchEngine:
     A *state* is any object with the ``_ReqState`` row contract:
     ``q_reps`` ([1, Lq, d] on this engine's device), ``q_valid_j``
     ([Lq]), ``priority`` / ``seq`` / ``deadline_s`` (scheduling),
-    ``scores`` (np [n] float32), ``n`` / ``n_done`` (completion), and
-    ``stats`` (:class:`RerankStats`).
+    ``scores`` (np [n] float32), ``n`` / ``n_done`` (completion),
+    ``t_enqueue`` (set here at :meth:`enqueue`; cleared once the state's
+    queue wait is counted) and ``stats`` (:class:`RerankStats`).
 
     ``device`` pins the engine to one device of the serving mesh: params
     are copied there once, every staged array is ``device_put`` there, and
@@ -621,6 +654,7 @@ class BatchEngine:
     def enqueue(self, state) -> None:
         """Admit a state's candidate rows into the next drain's packing
         pool (ordering applied at drain time via the policy)."""
+        state.t_enqueue = time.perf_counter()
         self._waiting.append(state)
 
     # -- scheduling ----------------------------------------------------------
@@ -658,70 +692,86 @@ class BatchEngine:
         With the hot-doc cache enabled, only the *misses* are gathered and
         shipped (bucket-padded so the decode/insert jits see O(log B)
         shapes); hit rows are just slot numbers into the device pool.
-        -> (qr, qv, payload, load_dt).  The clock stops only after
+        -> (qr, qv, payload, clocks).  ``clocks.load_s`` stops only after
         ``block_until_ready`` on everything staged — ``device_put`` is
         async, and an unblocked timestamp silently books the H2D copy
         under the next combine phase."""
-        t0 = time.perf_counter()
+        clocks = _StageClocks(t_start=time.perf_counter())
         faults.hit("engine.stage", tag=self.fault_tag)
         faults.hit("index.gather", tag=self.fault_tag, index=self.index,
                    doc_ids=[r[2] for r in plan.rows])
         if self._doc_cache is not None:
-            payload = self._stage_cached(plan)
+            payload = self._stage_cached(plan, clocks)
         else:
             gather_raw = getattr(self.index, "gather_raw", None)
-            if gather_raw is not None:
-                parts, dvalid = gather_raw(
-                    [r[2] for r in plan.rows], pad_to=self.cfg.max_doc_len,
-                    streams=self._gather_streams)
-            else:                          # index stand-ins without codecs
-                reps, dvalid = self.index.gather(
-                    [r[2] for r in plan.rows], pad_to=self.cfg.max_doc_len)
-                parts = {"reps": reps}
+            with span(spans.GATHER) as sp:
+                if gather_raw is not None:
+                    parts, dvalid = gather_raw(
+                        [r[2] for r in plan.rows],
+                        pad_to=self.cfg.max_doc_len,
+                        streams=self._gather_streams)
+                else:                      # index stand-ins without codecs
+                    reps, dvalid = self.index.gather(
+                        [r[2] for r in plan.rows],
+                        pad_to=self.cfg.max_doc_len)
+                    parts = {"reps": reps}
+            clocks.gather_s = sp.s
             h2d = sum(np.asarray(a).nbytes for a in parts.values())
-            payload = {"parts": jax.device_put(parts, self.device),
-                       "valid": jax.device_put(dvalid, self.device),
+            payload = {"parts": parts, "valid": dvalid,
                        "h2d_bytes": h2d + np.asarray(dvalid).nbytes}
-        last = next(s for s, _, _ in reversed(plan.rows) if s is not None)
-        qr = jnp.concatenate(
-            [(s or last).q_reps for s, _, _ in plan.rows], axis=0)
-        qv = jnp.stack([(s or last).q_valid_j for s, _, _ in plan.rows])
-        jax.block_until_ready((qr, qv, payload))
-        return qr, qv, payload, time.perf_counter() - t0
+        with span(spans.SHIP):
+            for k in ("parts", "valid", "miss_parts"):
+                if payload.get(k) is not None:
+                    payload[k] = jax.device_put(payload[k], self.device)
+            last = next(s for s, _, _ in reversed(plan.rows)
+                        if s is not None)
+            qr = jnp.concatenate(
+                [(s or last).q_reps for s, _, _ in plan.rows], axis=0)
+            qv = jnp.stack([(s or last).q_valid_j for s, _, _ in plan.rows])
+            jax.block_until_ready((qr, qv, payload))
+        clocks.load_s = time.perf_counter() - clocks.t_start
+        return qr, qv, payload, clocks
 
-    def _stage_cached(self, plan: _Plan):
+    def _stage_cached(self, plan: _Plan, clocks: _StageClocks):
         """Cache-aware staging: plan pages (LRU bump + miss admission) and
-        gather/ship only the miss rows, staged at the planned page-table
-        width so they scatter straight into the page pools."""
+        gather only the miss rows, staged at the planned page-table width
+        so they scatter straight into the page pools (``_stage`` ships
+        them)."""
         cache = self._doc_cache
-        ids = [r[2] for r in plan.rows]
-        # hit/miss accounting over *real* candidate rows only — the
-        # micro-batch shape pads (state None, always trailing) would
-        # otherwise skew the hit rates (pack_fill already excludes them)
-        real_ids = [d for s, _, d in plan.rows if s is not None]
-        lens = self._doc_lens[ids] if self._doc_lens is not None else None
-        page_table, miss_ids, miss_pages = cache.plan(
-            ids, lengths=lens, n_real=len(real_ids))
-        fresh = set(miss_ids)
-        n_miss_rows = sum(1 for d in real_ids if d in fresh)
-        payload = {"page_table": page_table, "miss_pages": None,
-                   "miss_parts": None, "miss_valid": None, "h2d_bytes": 0,
-                   "n_miss_rows": n_miss_rows, "n_rows": len(real_ids)}
+        with span(spans.PLAN) as sp:
+            ids = [r[2] for r in plan.rows]
+            # hit/miss accounting over *real* candidate rows only — the
+            # micro-batch shape pads (state None, always trailing) would
+            # otherwise skew the hit rates (pack_fill already excludes them)
+            real_ids = [d for s, _, d in plan.rows if s is not None]
+            lens = self._doc_lens[ids] if self._doc_lens is not None else None
+            page_table, miss_ids, miss_pages = cache.plan(
+                ids, lengths=lens, n_real=len(real_ids))
+            fresh = set(miss_ids)
+            n_miss_rows = sum(1 for d in real_ids if d in fresh)
+            payload = {"page_table": page_table, "miss_pages": None,
+                       "miss_parts": None, "miss_valid": None,
+                       "h2d_bytes": 0, "n_miss_rows": n_miss_rows,
+                       "n_rows": len(real_ids)}
+            if miss_ids:
+                bucket = cache.bucket(len(miss_ids), self.micro_batch)
+                pad = bucket - len(miss_ids)
+                padded_ids = miss_ids + [miss_ids[-1]] * pad
+                pages = (np.concatenate([miss_pages,
+                                         np.repeat(miss_pages[-1:], pad, 0)])
+                         if pad else miss_pages)
+        clocks.plan_s = sp.s
         if miss_ids:
-            bucket = cache.bucket(len(miss_ids), self.micro_batch)
-            pad = bucket - len(miss_ids)
-            padded_ids = miss_ids + [miss_ids[-1]] * pad
-            pages = (np.concatenate([miss_pages,
-                                     np.repeat(miss_pages[-1:], pad, 0)])
-                     if pad else miss_pages)
-            parts, valid = self.index.gather_raw(
-                padded_ids, pad_to=pages.shape[1] * cache.page_tokens,
-                streams=self._cache_streams)
+            with span(spans.GATHER) as sp:
+                parts, valid = self.index.gather_raw(
+                    padded_ids, pad_to=pages.shape[1] * cache.page_tokens,
+                    streams=self._cache_streams)
+            clocks.gather_s = sp.s
             payload["miss_pages"] = pages
             payload["h2d_bytes"] = (
                 sum(np.asarray(a).nbytes for a in parts.values())
                 + np.asarray(valid).nbytes)
-            payload["miss_parts"] = jax.device_put(parts, self.device)
+            payload["miss_parts"] = parts
             payload["miss_valid"] = valid
         return payload
 
@@ -735,17 +785,21 @@ class BatchEngine:
             try:
                 out_q.put((plan, *self._stage(plan), None))
             except Exception as e:                    # noqa: BLE001
-                out_q.put((plan, None, None, None, 0.0, e))
+                out_q.put((plan, None, None, None, None, e))
 
     def drain(self) -> list:
         """Run the scheduler until every enqueued state is fully scored.
         Returns the *completed states* in completion order (the composer
         turns them into responses)."""
-        t_wall = time.perf_counter()
+        with span(spans.DRAIN) as sp:
+            done = self._drain()
+        self.stats.wall_s += sp.s
+        return done
+
+    def _drain(self) -> list:
         done: list = []
         self._admit_waiting()
         if not self._rows and not self._replans:
-            self.stats.wall_s += time.perf_counter() - t_wall
             return done
         if self.prefetch_depth == 0:
             # synchronous debug path: no prefetch thread, stage + score
@@ -759,7 +813,6 @@ class BatchEngine:
                     self._score_plan(plan, *staged, done)
                 except Exception as e:                # noqa: BLE001
                     self._fail_plan(plan, e, done)
-            self.stats.wall_s += time.perf_counter() - t_wall
             return done
 
         in_q: queue.Queue = queue.Queue()
@@ -778,7 +831,9 @@ class BatchEngine:
                     inflight += 1
                 if inflight == 0:
                     break
-                plan, qr, qv, payload, load_dt, err = out_q.get()
+                with span(spans.STAGE_WAIT) as sp:
+                    plan, qr, qv, payload, clocks, err = out_q.get()
+                self.stats.stage_wait_s += sp.s
                 inflight -= 1
                 if err is not None:
                     # fault isolation: a staging error (bad gather, H2D
@@ -788,7 +843,7 @@ class BatchEngine:
                     self._fail_plan(plan, err, done)
                     continue
                 try:
-                    self._score_plan(plan, qr, qv, payload, load_dt, done)
+                    self._score_plan(plan, qr, qv, payload, clocks, done)
                 except Exception as e:                # noqa: BLE001
                     self._fail_plan(plan, e, done)
         finally:
@@ -800,7 +855,6 @@ class BatchEngine:
                 except queue.Empty:
                     pass
                 worker.join(timeout=0.05)
-        self.stats.wall_s += time.perf_counter() - t_wall
         return done
 
     def _fail_plan(self, plan: _Plan, err: BaseException, done: list) -> None:
@@ -853,8 +907,9 @@ class BatchEngine:
             cache = self._doc_cache
             mp = payload["miss_parts"]
             if mp is not None:
-                cache.insert(payload["miss_pages"], mp,
-                             payload["miss_valid"])
+                with span(spans.INSERT):
+                    cache.insert(payload["miss_pages"], mp,
+                                 payload["miss_valid"])
             self.stats.n_doc_cache_miss += payload["n_miss_rows"]
             self.stats.n_doc_cache_hit += (payload["n_rows"]
                                            - payload["n_miss_rows"])
@@ -883,17 +938,25 @@ class BatchEngine:
         self.stats.n_join_dispatch += 1
         return self._join(self.params, qr, qv, st, dval)
 
-    def _score_plan(self, plan: _Plan, qr, qv, payload, load_dt: float,
-                    done: list):
+    def _score_plan(self, plan: _Plan, qr, qv, payload,
+                    clocks: _StageClocks, done: list):
         rows = plan.rows
-        t0 = time.perf_counter()
-        scores = np.asarray(jax.device_get(
-            self._score_batch(qr, qv, payload)))
-        dt = time.perf_counter() - t0
-
         states = [s for s, _, _ in rows if s is not None]
-        counts = Counter(id(s) for s in states)
         uniq = {id(s): s for s in states}
+        for s in uniq.values():
+            if s.t_enqueue is not None:   # the state's first staged batch
+                wait = clocks.t_start - s.t_enqueue
+                s.t_enqueue = None
+                s.stats.queue_wait_s = wait
+                self.stats.queue_wait_s += wait
+                self.stats.n_queue_waits += 1
+        with span(spans.DISPATCH) as sp_dispatch:
+            out = self._score_batch(qr, qv, payload)
+        with span(spans.DEVICE_GET) as sp_get:
+            scores = np.asarray(jax.device_get(out))
+        dt = sp_dispatch.s + sp_get.s
+        load_dt = clocks.load_s
+
         deadline = self.policy.batch_deadline(
             [s.deadline_s for s in uniq.values()])
         if self.policy.should_redispatch(dt, deadline, len(states),
@@ -916,12 +979,10 @@ class BatchEngine:
         self.stats.n_rows += n_real
         self.stats.n_pad_rows += len(rows) - n_real
         self.stats.load_s += load_dt
+        self.stats.plan_s += clocks.plan_s
+        self.stats.gather_s += clocks.gather_s
         self.stats.combine_s += dt
-        for sid, cnt in counts.items():
-            s = uniq[sid]
-            frac = cnt / n_real
-            s.stats.load_s += load_dt * frac
-            s.stats.combine_s += dt * frac
+        self.stats.dispatch_s += sp_dispatch.s
         for i, (s, ci, _) in enumerate(rows):
             if s is None:
                 continue
@@ -1013,8 +1074,7 @@ class RankingService:
             use_layer_kv=use_layer_kv, join_fn=join_fn,
             doc_cache_mb=doc_cache_mb, page_tokens=page_tokens,
             page_bucket=page_bucket, device=device)
-        self._encode = encode_fn or jax.jit(
-            lambda p, t, v: P.encode_query(p, cfg, t, v))
+        self._encode = encode_fn or encode_query_jit(cfg)
         self._qcache: OrderedDict = OrderedDict()
         self._cache_size = cache_size
         self._seq = 0
@@ -1131,12 +1191,11 @@ class RankingService:
                 scores=np.zeros((0,), np.float32), stats=state.stats,
                 latency_s=0.0))
             return rid
-        t0 = time.perf_counter()
-        state.q_reps = self._query_reps(np.asarray(req.q_tokens),
-                                        np.asarray(req.q_valid))
-        dt = time.perf_counter() - t0
-        state.stats.query_encode_s = dt
-        self.stats.query_encode_s += dt
+        with span(spans.ENCODE) as sp:
+            state.q_reps = self._query_reps(np.asarray(req.q_tokens),
+                                            np.asarray(req.q_valid))
+        state.stats.query_encode_s = sp.s
+        self.stats.query_encode_s += sp.s
         state.q_valid_j = jax.device_put(np.asarray(req.q_valid),
                                          self.engine.device)
         self.engine.enqueue(state)
@@ -1186,16 +1245,17 @@ class RankingService:
         return done
 
     def _finalize(self, state: _ReqState) -> RankResponse:
-        order = np.argsort(-state.scores)
-        ids = list(state.req.doc_ids)
-        failed = sorted(set(state.failed_idx))
-        if failed:
-            self.stats.n_degraded += 1
-        return RankResponse(
-            request_id=state.rid,
-            doc_ids=[ids[i] for i in order],
-            scores=state.scores[order],
-            stats=state.stats,
-            latency_s=time.perf_counter() - state.t_submit,
-            degraded=bool(failed),
-            failed_doc_ids=[ids[i] for i in failed])
+        with span(spans.FINALIZE):
+            order = np.argsort(-state.scores)
+            ids = list(state.req.doc_ids)
+            failed = sorted(set(state.failed_idx))
+            if failed:
+                self.stats.n_degraded += 1
+            return RankResponse(
+                request_id=state.rid,
+                doc_ids=[ids[i] for i in order],
+                scores=state.scores[order],
+                stats=state.stats,
+                latency_s=time.perf_counter() - state.t_submit,
+                degraded=bool(failed),
+                failed_doc_ids=[ids[i] for i in failed])

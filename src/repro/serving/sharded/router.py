@@ -63,12 +63,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import prettr as P
+from repro.serving import spans
 from repro.serving.service import (BatchEngine, RankRequest, RankResponse,
                                    RerankStats, SchedulerPolicy,
                                    ServiceOverloadError, ServiceStats,
-                                   validate_doc_routing,
+                                   encode_query_jit, validate_doc_routing,
                                    validate_index_compat)
+from repro.serving.spans import span
 from repro.serving.sharded.worker import ShardTask, ShardWorker
 
 
@@ -260,8 +261,7 @@ class RankingRouter:
         self.health = [WorkerHealth(s, dead_after=dead_after)
                        for s in range(self.n_shards)]
         self.params = params
-        self._encode = encode_fn or jax.jit(
-            lambda p, t, v: P.encode_query(p, cfg, t, v))
+        self._encode = encode_fn or encode_query_jit(cfg)
         self._qcache: OrderedDict = OrderedDict()
         self._cache_size = cache_size
         self._seq = 0
@@ -354,12 +354,11 @@ class RankingRouter:
                 scores=np.zeros((0,), np.float32), stats=rec.stats,
                 latency_s=0.0))
             return rid
-        t0 = time.perf_counter()
-        q_reps = self._query_reps(np.asarray(req.q_tokens),
-                                  np.asarray(req.q_valid))
-        dt = time.perf_counter() - t0
-        rec.stats.query_encode_s = dt
-        self._admission_stats.query_encode_s += dt
+        with span(spans.ENCODE) as sp:
+            q_reps = self._query_reps(np.asarray(req.q_tokens),
+                                      np.asarray(req.q_valid))
+        rec.stats.query_encode_s = sp.s
+        self._admission_stats.query_encode_s += sp.s
         q_valid = jnp.asarray(req.q_valid)
         # the fallback engine re-scores with the router's own uncommitted
         # copies (a dead worker's device may be gone with it)
@@ -605,8 +604,9 @@ class RankingRouter:
         if good:
             rec.scores[task.cand_idx[good]] = task.scores[good]
             rec.pending_rows -= len(good)
-        rec.stats.load_s += task.stats.load_s
-        rec.stats.combine_s += task.stats.combine_s
+        # the request is answered when its slowest shard task is
+        rec.stats.queue_wait_s = max(rec.stats.queue_wait_s,
+                                     task.stats.queue_wait_s)
         rec.stats.n_redispatch += task.stats.n_redispatch
         self._maybe_finish(rec, done)
         if failed:

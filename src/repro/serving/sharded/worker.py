@@ -46,7 +46,8 @@ class ShardTask:
 
     __slots__ = ("req", "rid", "seq", "n", "priority", "deadline_s",
                  "q_reps", "q_valid_j", "scores", "n_done", "t_submit",
-                 "stats", "cand_idx", "shard_id", "failed_idx", "error")
+                 "t_enqueue", "stats", "cand_idx", "shard_id", "failed_idx",
+                 "error")
 
     def __init__(self, rid: str, seq: int, doc_ids, cand_idx, *,
                  priority: int = 0, deadline_s: float | None = None,
@@ -62,6 +63,7 @@ class ShardTask:
         self.scores = np.zeros(self.n, np.float32)
         self.n_done = 0
         self.t_submit = time.perf_counter()
+        self.t_enqueue = None             # set by BatchEngine.enqueue
         self.stats = RerankStats(n_docs=self.n)
         self.cand_idx = np.asarray(cand_idx, np.int64)
         self.shard_id = shard_id
