@@ -315,12 +315,12 @@ class PagedDocKV:
     dense per-batch copy (the pallas impl walks ``page_table`` in its
     index maps; the reference impls gather pages in-jit).
 
-    ``k``/``v``: [P, Hkv, page, Dh] head-major pools (the device doc
-    cache's K/V layout); ``valid``: [P, page] int pool
-    (the cache's page 0 is all-zero, so padded page-table tails mask
-    themselves); ``page_table``: [B, nP] i32; ``k_scale``/``v_scale``:
-    optional [P, page, 1] fp32 per-token dequant scale pools when the
-    K/V pools hold raw int8 codec payload."""
+    ``k``/``v``: [P, page, Hkv * Dh] token-major pools (the device doc
+    cache's layout; fp16 K/V as their ``uint16`` bits); ``valid``:
+    [P, page] int pool (the cache's page 0 is all-zero, so padded
+    page-table tails mask themselves); ``page_table``: [B, nP] i32;
+    ``k_scale``/``v_scale``: optional [P, page] fp32 per-token dequant
+    scale pools when the K/V pools hold raw int8 codec payload."""
     k: Any
     v: Any
     valid: Any
@@ -385,8 +385,7 @@ def prepare_join(params, cfg: PreTTRConfig, q_reps, q_valid, doc_store,
     ``(k, v)`` raw floats each [B, Ld, n_kv_heads * dh];
     ``(k, v, k_scale, v_scale)`` int8 payload plus [B, Ld] fp32 scales
     (dequantized inside the join impl); or a :class:`PagedDocKV` straight
-    from the device doc cache (head-major [P, Hkv, page, Dh] K/V pools,
-    [P, page] scale pools — reshaped to the kernel's [P, page, 1] here)."""
+    from the device doc cache, passed on as the cache holds it."""
     bcfg = cfg.backbone
     x_d = _decode_doc_store(params, cfg, doc_store)
     doc_k = doc_v = doc_k_scale = doc_v_scale = doc_kv_paged = None
@@ -398,14 +397,7 @@ def prepare_join(params, cfg: PreTTRConfig, q_reps, q_valid, doc_store,
         b, ld = x_d.shape[0], x_d.shape[1]
         hkv, dh = bcfg.n_kv_heads, bcfg.dh
         if isinstance(doc_kv, PagedDocKV):
-            page = doc_kv.k.shape[2]
-            doc_kv_paged = PagedDocKV(
-                k=doc_kv.k, v=doc_kv.v, valid=doc_kv.valid,
-                page_table=doc_kv.page_table,
-                k_scale=(None if doc_kv.k_scale is None
-                         else doc_kv.k_scale.reshape(-1, page, 1)),
-                v_scale=(None if doc_kv.v_scale is None
-                         else doc_kv.v_scale.reshape(-1, page, 1)))
+            doc_kv_paged = doc_kv
         elif len(doc_kv) == 4:
             k, v, doc_k_scale, doc_v_scale = doc_kv
             # raw int8 payload: keep the narrow dtype — the join impl

@@ -12,14 +12,7 @@ import jax.numpy as jnp
 from repro.kernels.join_attention.kernel import (join_attention_pallas,
                                                  join_attention_pallas_paged)
 from repro.kernels.masking import last_valid_lengths
-from repro.kernels.tpu import interpret_mode, sublane
-
-
-def _kernel_kv(x):
-    """fp16 doc K/V cross the kernel boundary as ``uint16`` bits (the
-    kernel widens them in registers); other dtypes pass as they are."""
-    return (jax.lax.bitcast_convert_type(x, jnp.uint16)
-            if x.dtype == jnp.float16 else x)
+from repro.kernels.tpu import as_f16_bits, interpret_mode, sublane
 
 
 def _q_segment_operands(q, kq, vq, kq_valid, block_q):
@@ -89,7 +82,9 @@ def join_flash_attention(q, kq, vq, kd, vd, kq_valid=None, kd_valid=None,
             vd_scales = jnp.pad(vd_scales, ((0, 0), (0, pad_d)))
         kd_scales = kd_scales[..., None]    # [B, Ld, 1] — row-broadcast
         vd_scales = vd_scales[..., None]
-    out = join_attention_pallas(q, kq, vq, _kernel_kv(kd), _kernel_kv(vd),
+    # fp16 doc K/V cross the kernel boundary as ``uint16`` bits (the kernel
+    # widens them in registers)
+    out = join_attention_pallas(q, kq, vq, as_f16_bits(kd), as_f16_bits(vd),
                                 dlen.astype(jnp.int32),
                                 kq_valid, kd_valid[:, None, :],
                                 block_q=bq, block_k=bk, interpret=interpret,
@@ -104,36 +99,33 @@ def join_flash_attention_paged(q, kq, vq, kd_pages, vd_pages, page_table,
                                block_q: int = 128,
                                interpret: bool | None = None):
     """Paged doc segment: doc K/V stay in the device doc cache's token-page
-    pools and the kernel's doc-segment index maps walk the page table — no
-    per-batch dense KV copy is ever materialized.
+    pools and the kernel's page index map walks the page table — no
+    per-batch dense KV copy is ever materialized, and every pool goes to
+    the kernel as it lies in the cache (no relayout, cast or pad).
 
     q: [B, Hq, Sq, D]; kq, vq: [B, Hkv, Lq, D];
-    kd_pages, vd_pages: [P, Hkv, page, D] pools (``page`` a sublane
-    multiple — the cache rounds it up); page_table: [B, nP] i32 pool page
-    per (row, doc tile), tail entries pointing at the cache's all-zero
-    page 0; dval_pages: [P, page] token-validity pool (page 0 is all-zero,
-    so padded tails mask themselves); kd_scale_pages / vd_scale_pages:
-    optional [P, page, 1] fp32 scale pools for raw-int8 KV pools.
+    kd_pages, vd_pages: [P, page, Hkv * D] token-major pools;
+    page_table: [B, nP] i32 pool page per (row, doc page), tail entries
+    pointing at the cache's all-zero page 0; dval_pages: [P, page]
+    token-validity pool (page 0 is all-zero, so padded tails mask
+    themselves); kd_scale_pages / vd_scale_pages: optional [P, page] fp32
+    scale pools for raw-int8 KV pools.
     Returns [B, Hq, Sq, D]; the doc segment spans nP * page assembled
     positions."""
     if interpret is None:
         interpret = interpret_mode()
     b, sq = q.shape[0], q.shape[2]
     page_table = page_table.astype(jnp.int32)
-    dval_pages = dval_pages.astype(jnp.int32)
-    # valid length of each assembled row, gathered from the validity pool
-    # (tiny [B, nP*page] int gather; the KV pools are never densified)
-    dval_rows = dval_pages[page_table].reshape(b, -1)
+    # validity and valid length of each assembled row, gathered from the
+    # validity pool (a tiny [B, nP * page] gather; the K/V and scale pools
+    # go to the kernel whole and are never densified)
+    dval_rows = dval_pages[page_table].reshape(b, -1).astype(jnp.int32)
     dlen = last_valid_lengths(dval_rows, dval_rows.shape[1])
     q, kq, vq, kq_valid, bq = _q_segment_operands(q, kq, vq, kq_valid,
                                                   block_q)
-    if kd_scale_pages is not None:
-        kd_scale_pages = kd_scale_pages.astype(jnp.float32)
-        vd_scale_pages = vd_scale_pages.astype(jnp.float32)
     out = join_attention_pallas_paged(
-        q, kq, vq, _kernel_kv(kd_pages), _kernel_kv(vd_pages), page_table,
-        dlen.astype(jnp.int32),
-        kq_valid, dval_pages[:, None, :],
+        q, kq, vq, as_f16_bits(kd_pages), as_f16_bits(vd_pages), page_table,
+        dlen, kq_valid, dval_rows.reshape(-1, 1, dval_pages.shape[1]),
         block_q=bq, interpret=interpret,
         kd_scale_pages=kd_scale_pages, vd_scale_pages=vd_scale_pages)
     return out[:, :, :sq]

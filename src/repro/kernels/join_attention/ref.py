@@ -8,6 +8,8 @@ import math
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.tpu import from_f16_bits
+
 NEG_INF = -1e30
 
 
@@ -56,16 +58,10 @@ def join_attention_ref_quant(q, kq, vq, kd_q, vd_q, kd_scales, vd_scales,
 def pages_to_dense(pages, page_table):
     """Densify token-page pools via a page table.
     pages: [P, page, ...]; page_table: [B, nP] i32.
-    Returns [B, nP * page, ...] in assembled row order."""
-    g = pages[page_table]
-    return g.reshape((g.shape[0], g.shape[1] * g.shape[2]) + g.shape[3:])
-
-
-def kv_pages_to_dense(pages, page_table):
-    """Densify K/V pools in the paged kernel's head-major layout.
-    pages: [P, Hkv, page, D]; page_table: [B, nP] i32.
-    Returns token-major [B, nP * page, Hkv, D]."""
-    g = jnp.moveaxis(pages[page_table], 2, 3)    # [B, nP, page, Hkv, D]
+    Returns [B, nP * page, ...] in assembled row order.  A ``uint16`` pool
+    holds fp16 bits (the TPU's Pallas kernels take no fp16) and densifies
+    back to fp16."""
+    g = from_f16_bits(pages[page_table])
     return g.reshape((g.shape[0], g.shape[1] * g.shape[2]) + g.shape[3:])
 
 
@@ -75,13 +71,19 @@ def join_attention_ref_paged(q, kq, vq, kd_pages, vd_pages, page_table,
     """Densify-then-attend oracle for the paged doc segment: gather pages
     into dense [B, Hkv, Ld, D] rows, optionally dequantize, then run the
     fp32 oracle.  Pool layouts match the paged kernel
-    ([P, Hkv, page, D] KV, [P, page] validity, [P, page, 1] scales)."""
-    kd = jnp.moveaxis(kv_pages_to_dense(kd_pages, page_table), 2, 1)
-    vd = jnp.moveaxis(kv_pages_to_dense(vd_pages, page_table), 2, 1)
+    ([P, page, Hkv * D] KV, uint16 for fp16 bits; [P, page] validity and
+    scales)."""
+    b, hkv, _, d = kq.shape
+
+    def heads(pages):               # [B, Ld, Hkv * D] -> [B, Hkv, Ld, D]
+        rows = pages_to_dense(pages, page_table)
+        return jnp.moveaxis(rows.reshape(b, -1, hkv, d), 2, 1)
+
+    kd, vd = heads(kd_pages), heads(vd_pages)
     kd_valid = pages_to_dense(dval_pages, page_table)
     if kd_scale_pages is not None:
-        kd_scales = pages_to_dense(kd_scale_pages, page_table)[..., 0]
-        vd_scales = pages_to_dense(vd_scale_pages, page_table)[..., 0]
+        kd_scales = pages_to_dense(kd_scale_pages, page_table)
+        vd_scales = pages_to_dense(vd_scale_pages, page_table)
         return join_attention_ref_quant(q, kq, vq, kd, vd, kd_scales,
                                         vd_scales, kq_valid=kq_valid,
                                         kd_valid=kd_valid)

@@ -9,6 +9,9 @@
   dtype), so fp16 streams cross the kernel boundary bitcast to ``uint16``
   and are widened / narrowed in registers with integer ops.  Both are
   exact: the narrowing rounds to nearest-even like XLA's ``convert``.
+* :func:`as_f16_bits` / :func:`from_f16_bits` — that bitcast and its
+  inverse, for arrays that are kept as bits (the doc cache's pools: on
+  the TPU a bitcast of a whole pool is a copy).
 """
 from __future__ import annotations
 
@@ -27,6 +30,18 @@ def sublane(dtype) -> int:
     32-bit, 16 for 16-bit, 32 for 8-bit types.  Block second-minor dims
     that are not the whole array must be multiples of it."""
     return 8 * 4 // jnp.dtype(dtype).itemsize
+
+
+def as_f16_bits(x):
+    """fp16 as its ``uint16`` bit patterns; other dtypes as they are."""
+    return (jax.lax.bitcast_convert_type(x, jnp.uint16)
+            if x.dtype == jnp.float16 else x)
+
+
+def from_f16_bits(x):
+    """``uint16`` bit patterns back to fp16; other dtypes as they are."""
+    return (jax.lax.bitcast_convert_type(x, jnp.float16)
+            if x.dtype == jnp.uint16 else x)
 
 
 def f16_bits_to_f32(h):

@@ -39,9 +39,9 @@ Kinds and their call contracts (all arrays in **model layout**):
   ``kd``/``vd`` as raw int8 codec payload dequantized on the fly (the
   reference impls widen before the concat, the pallas impl dequantizes
   in-register inside the KV tile loop); ``paged`` (an object with
-  ``k``/``v`` ``[P, Hkv, page, D]`` pools, ``page_table`` ``[B, nP]``,
+  ``k``/``v`` ``[P, page, Hkv * D]`` pools, ``page_table`` ``[B, nP]``,
   ``valid`` ``[P, page]``, optional ``k_scale``/``v_scale``
-  ``[P, page, 1]`` — ``repro.core.prettr.PagedDocKV``) replaces ``kd``/
+  ``[P, page]`` — ``repro.core.prettr.PagedDocKV``) replaces ``kd``/
   ``vd`` entirely with the device doc cache's token-page pools: the
   reference impls gather the pages into dense rows in-jit, the pallas
   impl walks the page table in its index maps.
@@ -82,7 +82,7 @@ from repro.kernels.decode_attention import flash_decode_attention
 from repro.kernels.fused_compress import fused_compress, fused_decompress
 from repro.kernels.join_attention import (join_flash_attention,
                                           join_flash_attention_paged,
-                                          kv_pages_to_dense, pages_to_dense)
+                                          pages_to_dense)
 from repro.kernels.split_attention import split_flash_attention
 from repro.models import layers as L
 
@@ -293,19 +293,24 @@ def _concat_join_operands(q, kq, vq, kd, vd, kq_valid, kd_valid):
     return k, v, k_valid
 
 
-def _densify_paged(paged, kd_valid):
+def _densify_paged(paged, kd_valid, heads):
     """Reference-impl form of the paged doc segment: gather the cache's
-    token pages into dense ``[B, Ld, Hkv, D]`` rows (inside the caller's
-    jit), sliced to the caller's dense doc length so the concat cores see
-    exactly the shapes the slot-cache path fed them — which is what keeps
-    paged scores bit-exact vs the slot cache on float KV."""
+    token pages into dense ``[B, Ld, Hkv, D]`` rows (``heads`` is ``(Hkv,
+    D)``; inside the caller's jit), sliced to the caller's dense doc
+    length so the concat cores see exactly the shapes the slot-cache path
+    fed them — which is what keeps paged scores bit-exact vs the slot
+    cache on float KV."""
     ld = kd_valid.shape[1] if kd_valid is not None else None
-    kd = kv_pages_to_dense(paged.k, paged.page_table)[:, :ld]
-    vd = kv_pages_to_dense(paged.v, paged.page_table)[:, :ld]
+
+    def rows(pool):
+        return pages_to_dense(pool, paged.page_table)[:, :ld]
+
+    kd, vd = (rows(pool) for pool in (paged.k, paged.v))
+    kd = kd.reshape(kd.shape[:2] + tuple(heads))
+    vd = vd.reshape(kd.shape)
     kd_scale = vd_scale = None
     if paged.k_scale is not None:
-        kd_scale = pages_to_dense(paged.k_scale, paged.page_table)[:, :ld, 0]
-        vd_scale = pages_to_dense(paged.v_scale, paged.page_table)[:, :ld, 0]
+        kd_scale, vd_scale = rows(paged.k_scale), rows(paged.v_scale)
     return kd, vd, kd_scale, vd_scale
 
 
@@ -343,7 +348,8 @@ def _join_plain(q, kq, vq, kd, vd, *, cfg, scale, q_valid=None,
     # shapes, so fused-vs-concat stays bit-exact under this impl
     b, sq = q.shape[0], q.shape[1]
     if paged is not None:
-        kd, vd, kd_scale, vd_scale = _densify_paged(paged, kd_valid)
+        kd, vd, kd_scale, vd_scale = _densify_paged(paged, kd_valid,
+                                                    kq.shape[2:])
         if kd_scale is None:        # float pools: slot-path dtype parity
             kd, vd = kd.astype(cfg.compute_dtype), vd.astype(cfg.compute_dtype)
     if kd_scale is not None:
@@ -365,7 +371,8 @@ def _join_blocked(q, kq, vq, kd, vd, *, cfg, scale, q_valid=None,
     del q_valid                       # parity with the blocked legacy impl
     b, sq = q.shape[0], q.shape[1]
     if paged is not None:
-        kd, vd, kd_scale, vd_scale = _densify_paged(paged, kd_valid)
+        kd, vd, kd_scale, vd_scale = _densify_paged(paged, kd_valid,
+                                                    kq.shape[2:])
         if kd_scale is None:        # float pools: slot-path dtype parity
             kd, vd = kd.astype(cfg.compute_dtype), vd.astype(cfg.compute_dtype)
     if kd_scale is not None:
@@ -391,9 +398,9 @@ def _join_pallas(q, kq, vq, kd, vd, *, cfg, scale, q_valid=None,
     kqt = kq.transpose(0, 2, 1, 3)
     vqt = vq.transpose(0, 2, 1, 3)
     if paged is not None:
-        # the kernel's doc-segment index maps walk the page table — the
-        # pools ([P, Hkv, page, D]) are already in kernel page layout and
-        # no dense per-batch KV copy is materialized
+        # the kernel's page index map walks the page table — the pools
+        # ([P, page, Hkv * D]) go in as the cache holds them and no dense
+        # per-batch KV copy is materialized
         out = join_flash_attention_paged(
             qt, kqt, vqt, paged.k, paged.v, paged.page_table, paged.valid,
             kq_valid=kq_valid, kd_scale_pages=paged.k_scale,

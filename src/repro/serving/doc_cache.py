@@ -13,10 +13,13 @@ the cache footprint is the narrow encoded payload: an int8 index holds
 ~4x more resident docs per MiB than the old decoded-float pools.
 
 Design: **token-page pools**, paged-attention style.  Each stream is one
-preallocated device tensor ``[n_pages, page_tokens, ...]`` — or, for
-the streams the caller names ``head_major`` (the layer-``l`` K/V), the
-``[n_pages, H, page_tokens, D]`` that the paged join kernel reads one
-``(page_tokens, D)`` tile at a time; an LRU map
+preallocated device tensor ``[n_pages, page_tokens, *row]`` in the row
+layout the index writes — the layer-``l`` K/V as ``[n_pages,
+page_tokens, Hkv * Dh]``, per-token scales as ``[n_pages, page_tokens]``
+— which is also the layout the paged join kernel reads in place, one
+lane-dense page per step (fp16 streams are pooled as their ``uint16``
+bits: the TPU's Pallas compiler takes no fp16, and a whole-pool bitcast
+would be a copy); an LRU map
 assigns each doc a list of ``ceil(len/page_tokens)`` pages, so short docs
 no longer pin whole max-length slots.  Batch assembly is a page-table
 gather (``pool[page_table]``) and miss insertion one scatter per stream —
@@ -58,7 +61,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.join_attention import kv_pages_to_dense, pages_to_dense
+from repro.kernels.join_attention import pages_to_dense
+from repro.kernels.tpu import as_f16_bits, interpret_mode, sublane
 
 
 @functools.partial(jax.jit, donate_argnums=0)
@@ -72,12 +76,12 @@ class DeviceDocCache:
     ``capacity_bytes`` bounds device memory; the page count is derived
     from the per-page footprint of ``streams`` — a ``{name: (dtype,
     row_shape)}`` spec as produced by ``IndexReader.streams_spec()`` —
-    plus one validity byte per token.  ``head_major`` maps the names of
-    streams to pool as ``[n_pages, H, page_tokens, D]`` (the paged join
-    kernel's K/V layout) to their ``(H, D)``; the cache owns that layout
-    on insert and in :meth:`dense`.  ``page_tokens=None`` (default)
+    plus one validity byte per token.  ``page_tokens=None`` (default)
     gives whole-doc pages (slot behavior); smaller values pack variable
-    -length docs tighter.  ``page_bucket=True`` lets :meth:`plan` shrink
+    -length docs tighter.  On a TPU a page is rounded up to whole native
+    tiles of every pool (32 rows, an int8 pool's), so no pool is padded
+    and the paged kernel reads each page where it lies; untiled memory
+    elsewhere rounds to 8.  ``page_bucket=True`` lets :meth:`plan` shrink
     the page-table width to the batch's longest doc (bucketed to powers
     of two) instead of the fixed ``pages_per_doc`` — fewer gathered
     bytes, at the cost of a few extra jit shapes.
@@ -88,12 +92,14 @@ class DeviceDocCache:
 
     def __init__(self, capacity_bytes: int, *, doc_len: int,
                  streams: dict, page_tokens: int | None = None,
-                 head_major: dict | None = None,
                  page_bucket: bool = False, min_slots: int = 2,
                  device=None):
         if page_tokens is None:
             page_tokens = doc_len
-        page_tokens = -(-int(page_tokens) // 8) * 8   # sublane multiple
+        # a native tile of an 8-bit pool has the most rows (32): a page of
+        # whole int8 tiles is whole tiles of every pool
+        unit = 8 if interpret_mode() else sublane(np.int8)
+        page_tokens = -(-int(page_tokens) // unit) * unit
         self.page_tokens = page_tokens
         self.pages_per_doc = -(-int(doc_len) // page_tokens)
         self.doc_len = int(doc_len)
@@ -103,13 +109,7 @@ class DeviceDocCache:
         self._streams = {
             name: (np.dtype(dt), tuple(shape))
             for name, (dt, shape) in streams.items()}
-        self._head_major = {n: tuple(hd) for n, hd in
-                            (head_major or {}).items()}
-        self._take = jax.jit(self.dense, static_argnums=0)
-        for n, (h, d) in self._head_major.items():
-            if int(np.prod(self._streams[n][1])) != h * d:
-                raise ValueError(f"stream {n!r} rows {self._streams[n][1]} "
-                                 f"do not hold {h} heads of {d}")
+        self._take = jax.jit(pages_to_dense)
         row_bytes = sum(
             dt.itemsize * int(np.prod(shape, dtype=np.int64))
             for dt, shape in self._streams.values()) + 1   # + valid byte
@@ -139,9 +139,10 @@ class DeviceDocCache:
             return self.put(jnp.zeros(shape, dt))
 
         self._pools = {
-            name: _alloc(self._pool_shape(n_pages, name), dt)
-            for name, (dt, _) in self._streams.items()}
-        #: device per-page validity (int8 — the paged kernel's dval pool)
+            name: _alloc((n_pages, page_tokens) + shape,
+                         self._pool_dtype(dt))
+            for name, (dt, shape) in self._streams.items()}
+        #: device per-page validity (int8; a batch gathers its rows)
         self.valid_pool = _alloc((n_pages, page_tokens), jnp.int8)
         self._valid_np = np.zeros((n_pages, page_tokens), bool)
         self._pages_of: OrderedDict[int, list[int]] = OrderedDict()  # LRU
@@ -158,20 +159,9 @@ class DeviceDocCache:
         cache is unpinned)."""
         return jax.device_put(x, self.device)
 
-    def _pool_shape(self, n_pages: int, name: str) -> tuple:
-        if name in self._head_major:
-            h, d = self._head_major[name]
-            return (n_pages, h, self.page_tokens, d)
-        return (n_pages, self.page_tokens) + self._streams[name][1]
-
-    def dense(self, name: str, pool, page_table):
-        """Densify stream ``name``'s pool through a page table (traceable:
-        the serving jits call it on :attr:`pools`) -> ``[B, W *
-        page_tokens, *row]`` — ``[B, W * page_tokens, H, D]`` for a
-        head-major stream."""
-        if name in self._head_major:
-            return kv_pages_to_dense(pool, page_table)
-        return pages_to_dense(pool, page_table)
+    @staticmethod
+    def _pool_dtype(dt):
+        return np.dtype(np.uint16) if dt == np.float16 else dt
 
     def __len__(self):
         return len(self._pages_of)
@@ -297,11 +287,7 @@ class DeviceDocCache:
         pages_dev = self.put(flat)
         for name, rows in parts.items():
             pool = self._pools[name]
-            rows = self.put(rows).astype(pool.dtype)
-            if name in self._head_major:           # -> [M*W, H, page, D]
-                rows = jnp.swapaxes(rows.reshape(
-                    (m * w, self.page_tokens) + self._head_major[name]),
-                    1, 2)
+            rows = as_f16_bits(self.put(rows).astype(self._streams[name][0]))
             rows = rows.reshape((m * w,) + pool.shape[1:])
             self._pools[name] = _scatter(pool, pages_dev, rows)
         valid = np.asarray(valid, bool).reshape(m * w, self.page_tokens)
@@ -319,7 +305,7 @@ class DeviceDocCache:
         directly inside jitted device code (its pool-fused assemble/score
         dispatches); ``take`` is the standalone accessor for tests."""
         pt = self.put(np.asarray(page_table, np.int32))
-        parts = {name: self._take(name, pool, pt)
+        parts = {name: self._take(pool, pt)
                  for name, pool in self._pools.items()}
         return parts, self.valid_rows(page_table)
 

@@ -416,6 +416,37 @@ def validate_index_compat(cfg: P.PreTTRConfig, index: TermRepIndex,
 # ---------------------------------------------------------------------------
 
 
+def _pool_reps(codec, rep_streams, pools, pt):
+    """A batch's document reps, gathered from the doc cache's pools
+    through the page table and decoded (inside the caller's jit)."""
+    if codec.decode_is_identity:
+        return pages_to_dense(pools["reps"], pt)
+    return codec.decode_group(
+        "reps", {s: pages_to_dense(pools[s], pt) for s in rep_streams})
+
+
+def pool_score_fn(cfg: P.PreTTRConfig, codec, rep_streams, kv_quant=None,
+                  *, fused: bool = True) -> Callable:
+    """The pallas backend's pool-fused scoring step, ``_pool_score(p, qr,
+    qv, pools, vpool, pt)``: the doc cache's layer-``l`` K/V (and, for an
+    int8 ``kv_quant`` codec, scale) pools go to the paged join kernel as
+    they lie, in a :class:`PagedDocKV` whose page table the kernel walks;
+    only the reps and the validity are gathered.  Built from the config
+    and the codecs alone, so it can be lowered from shapes."""
+    def _pool_score(p, qr, qv, pools, vpool, pt):
+        dval = pages_to_dense(vpool, pt).astype(bool)
+        x_d = _pool_reps(codec, rep_streams, pools, pt)
+        scales = ((None, None) if kv_quant is None else
+                  tuple(pools[kv_quant.scale_stream(g)]
+                        for g in ("layer_k", "layer_v")))
+        dkv = P.PagedDocKV(k=pools["layer_k"], v=pools["layer_v"],
+                           valid=vpool, page_table=pt,
+                           k_scale=scales[0], v_scale=scales[1])
+        return P.join_and_score(p, cfg, qr, qv, x_d, dval, doc_kv=dkv,
+                                fused=fused)
+    return _pool_score
+
+
 class BatchEngine:
     """The reusable micro-batch scheduler/packer/scorer.
 
@@ -549,7 +580,6 @@ class BatchEngine:
             # inside the pool-fused scoring jit, so an int8 index keeps
             # ~4x more docs resident per MiB than decoded-float pools
             spec = dict(codec.streams(index.rep_dim))
-            head_major = {}
             if self.use_layer_kv:
                 kvs = getattr(index, "kv_streams_spec", None)
                 spec.update(kvs() if kvs else {
@@ -557,15 +587,10 @@ class BatchEngine:
                                 (index.kv_dim,)),
                     "layer_v": (np.dtype(index.layer_kv["dtype"]),
                                 (index.kv_dim,))})
-                # K/V pools take the paged join kernel's layout
-                # ([P, Hkv, page, Dh]: one (page, Dh) tile per head)
-                bb = cfg.backbone
-                head_major = {n: (bb.n_kv_heads, bb.dh)
-                              for n in ("layer_k", "layer_v")}
             self._cache_streams = list(spec)
             cache = self._doc_cache = DeviceDocCache(
                 int(doc_cache_mb * 2**20), doc_len=cfg.max_doc_len,
-                streams=spec, page_tokens=page_tokens, head_major=head_major,
+                streams=spec, page_tokens=page_tokens,
                 page_bucket=page_bucket, min_slots=2 * self.micro_batch,
                 device=device)
             # pool-fused scoring, one `_join_pool` call per micro-batch and
@@ -584,50 +609,28 @@ class BatchEngine:
             use_kv, kvq = self.use_layer_kv, self._kv_quant
             rep_streams = list(codec.streams(index.rep_dim))
 
-            def _dense(pools, name, pt):
-                return cache.dense(name, pools[name], pt)
-
             def _pool_assemble(pools, vpool, pt):
                 dval = pages_to_dense(vpool, pt).astype(bool)
-                if codec.decode_is_identity:
-                    x_d = _dense(pools, "reps", pt)
-                else:
-                    x_d = codec.decode_group(
-                        "reps", {s: _dense(pools, s, pt) for s in rep_streams})
+                x_d = _pool_reps(codec, rep_streams, pools, pt)
                 dkv = None
                 if use_kv:
                     names = ["layer_k", "layer_v"]
                     if kvq:
                         names += [kv_codec.scale_stream("layer_k"),
                                   kv_codec.scale_stream("layer_v")]
-                    dkv = tuple(_dense(pools, n, pt) for n in names)
+                    dkv = tuple(pages_to_dense(pools[n], pt) for n in names)
                 return x_d, dval, dkv
 
             def _dense_score(p, qr, qv, x_d, dval, dkv):
                 return P.join_and_score(p, cfg, qr, qv, x_d, dval,
                                         doc_kv=dkv, fused=fused)
 
-            def _pool_score(p, qr, qv, pools, vpool, pt):
-                dval = pages_to_dense(vpool, pt).astype(bool)
-                if codec.decode_is_identity:
-                    x_d = _dense(pools, "reps", pt)
-                else:
-                    x_d = codec.decode_group(
-                        "reps", {s: _dense(pools, s, pt) for s in rep_streams})
-                dkv = P.PagedDocKV(
-                    k=pools["layer_k"], v=pools["layer_v"],
-                    valid=vpool, page_table=pt,
-                    k_scale=(pools[kv_codec.scale_stream("layer_k")]
-                             if kvq else None),
-                    v_scale=(pools[kv_codec.scale_stream("layer_v")]
-                             if kvq else None))
-                return P.join_and_score(p, cfg, qr, qv, x_d, dval,
-                                        doc_kv=dkv, fused=fused)
-
             attn_impl = getattr(getattr(cfg, "backbone", cfg), "attn_impl",
                                 "plain")
             if use_kv and attn_impl == "pallas":
-                self._join_pool = jax.jit(_pool_score)
+                self._join_pool = jax.jit(pool_score_fn(
+                    cfg, codec, rep_streams,
+                    kv_codec if kvq else None, fused=fused))
             else:
                 assemble = jax.jit(_pool_assemble)
                 score = jax.jit(_dense_score)

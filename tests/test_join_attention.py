@@ -129,31 +129,28 @@ def test_join_kernel_int8_in_kernel_dequant_bit_exact():
 
 
 def _paginate(kd, vd, kdv, page, kd_s=None, vd_s=None):
-    """Pack dense [B, Hkv, Ld, D] doc K/V into cache-layout page pools
-    ([P, Hkv, page, D]) with page 0 reserved all-zero; rows keep all their
-    pages (dense table) so the paged kernel sees the same assembled
-    positions as the dense kernel."""
+    """Pack dense [B, Hkv, Ld, D] doc K/V into the cache's token-major page
+    pools ([P, page, Hkv * D] K/V, [P, page] validity and scales) with
+    page 0 reserved all-zero; rows keep all their pages (dense table) so
+    the paged kernel sees the same assembled positions as the dense
+    kernel."""
     b, hkv, ld, d = kd.shape
     n_p = ld // page
 
-    def _pages(x):                  # [B, Hkv, nP*page, D] -> [B*nP, Hkv, page, D]
-        x = np.asarray(x).reshape(b, hkv, n_p, page, d)
-        return np.moveaxis(x, 2, 1).reshape(b * n_p, hkv, page, d)
+    def _pages(x, row):             # [B, nP * page, *row] -> [B * nP, page, *row]
+        x = np.asarray(x).reshape((b * n_p, page) + row)
+        return np.concatenate([np.zeros_like(x[:1]), x])
 
-    kd_r, vd_r = _pages(kd), _pages(vd)
-    zeros = np.zeros_like(kd_r[:1])
-    kd_pages = jnp.asarray(np.concatenate([zeros, kd_r]))
-    vd_pages = jnp.asarray(np.concatenate([zeros, vd_r]))
+    def _kv(x):                     # [B, Hkv, Ld, D] -> token rows
+        return jnp.asarray(_pages(np.moveaxis(np.asarray(x), 1, 2),
+                                  (hkv * d,)))
+
     pt = jnp.arange(1, 1 + b * n_p, dtype=jnp.int32).reshape(b, n_p)
-    dval = np.asarray(kdv, np.int32).reshape(b * n_p, page)
-    dval_pages = jnp.asarray(np.concatenate(
-        [np.zeros((1, page), np.int32), dval]))
-    out = [kd_pages, vd_pages, pt, dval_pages]
+    out = [_kv(kd), _kv(vd), pt,
+           jnp.asarray(_pages(np.asarray(kdv, np.int32), ()))]
     if kd_s is not None:
-        for s in (kd_s, vd_s):
-            s_r = np.asarray(s, np.float32).reshape(b * n_p, page, 1)
-            out.append(jnp.asarray(np.concatenate(
-                [np.zeros((1, page, 1), np.float32), s_r])))
+        out += [jnp.asarray(_pages(np.asarray(s, np.float32), ()))
+                for s in (kd_s, vd_s)]
     return out
 
 

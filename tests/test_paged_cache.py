@@ -254,6 +254,91 @@ def test_cache_multi_page_round_trip():
     assert not np.asarray(cache.pools["reps"][cache.ZERO_PAGE]).any()
 
 
+def test_cache_token_major_pools_insert_take():
+    """K/V streams pool as ``[P, page, Hkv * Dh]`` rows (fp16 as its
+    uint16 bits), per-token scales as ``[P, page]``: each pool page holds
+    the staged rows of its doc's page verbatim, and both ``take()`` and the
+    in-jit ``pages_to_dense`` give the staged bytes back, across a
+    multi-page doc and an eviction that recycles its pages."""
+    from repro.kernels.join_attention import pages_to_dense
+    streams = {"layer_k": (np.dtype(np.int8), (8,)),
+               "layer_k_scales": (np.dtype(np.float32), ()),
+               "layer_v": (np.dtype(np.float16), (8,))}
+    row_bytes = 8 + 4 + 16 + 1
+    cache = DeviceDocCache(8 * 8 * row_bytes, doc_len=20, streams=streams,
+                           page_tokens=8)           # 6 usable pages
+    assert cache.pages_per_doc == 3 and cache.capacity_pages == 8
+    assert cache.pools["layer_k"].shape == (8, 8, 8)
+    assert cache.pools["layer_k_scales"].shape == (8, 8)
+    assert cache.pools["layer_v"].dtype == np.uint16
+    rng = np.random.default_rng(5)
+
+    def staged(lens):
+        n = len(lens)
+        valid = np.arange(cache.padded_len)[None] < np.asarray(lens)[:, None]
+        rows = {
+            "layer_k": rng.integers(-127, 128, (n, cache.padded_len, 8),
+                                    dtype=np.int8),
+            "layer_k_scales": rng.random((n, cache.padded_len),
+                                         dtype=np.float32),
+            "layer_v": rng.standard_normal(
+                (n, cache.padded_len, 8)).astype(np.float16)}
+        return ({k: np.where(valid.reshape(valid.shape + (1,) * (v.ndim - 2)),
+                             v, 0).astype(v.dtype) for k, v in rows.items()},
+                valid)
+
+    def check(pt, miss_pages, rows, valid):
+        parts, got_valid = cache.take(pt)
+        np.testing.assert_array_equal(got_valid, valid)
+        for name, want in rows.items():
+            np.testing.assert_array_equal(np.asarray(parts[name]), want)
+            got = pages_to_dense(cache.pools[name], jnp.asarray(pt))
+            assert got.dtype == want.dtype
+            np.testing.assert_array_equal(np.asarray(got), want)
+            # each pool page holds its doc page's rows, token-major
+            pool = np.asarray(cache.pools[name])
+            for i, pages in enumerate(miss_pages):
+                for j, pg in enumerate(pages):
+                    if pg == cache.SCRATCH_PAGE:
+                        continue
+                    page_rows = want[i, 8 * j:8 * (j + 1)]
+                    if want.dtype == np.float16:
+                        page_rows = page_rows.view(np.uint16)
+                    np.testing.assert_array_equal(pool[pg], page_rows)
+
+    pt, miss_ids, miss_pages = cache.plan([10, 11], lengths=[20, 5])
+    rows, valid = staged([20, 5])
+    cache.insert(miss_pages, rows, valid)
+    check(pt, miss_pages, rows, valid)
+    old_pages = set(pt[0])
+    # a three-page miss evicts doc 10 (LRU) and reuses its pages
+    pt, miss_ids, miss_pages = cache.plan([11, 12], lengths=[5, 17])
+    assert miss_ids == [12] and cache.evictions == 1
+    assert set(miss_pages[0]) & old_pages
+    new_rows, new_valid = staged([17])
+    cache.insert(miss_pages, new_rows, new_valid)
+    want = {k: np.concatenate([rows[k][1:2], new_rows[k]]) for k in rows}
+    check(pt, [], want, np.concatenate([valid[1:2], new_valid]))
+    check(pt[1:], miss_pages, new_rows, new_valid)
+
+
+@pytest.mark.parametrize("on_tpu,requested,page", [
+    (False, 8, 8), (False, 12, 16), (True, 8, 32), (True, 128, 128),
+    (True, 130, 160)])
+def test_page_tokens_whole_tiles(monkeypatch, on_tpu, requested, page):
+    """On a TPU a page is whole native tiles of every pool (32 rows: the
+    int8 K/V and validity pools'), so the paged kernel reads pages where
+    they lie; off the chip, where memory is not tiled, it rounds to 8."""
+    import repro.serving.doc_cache as dc
+    monkeypatch.setattr(dc, "interpret_mode", lambda: not on_tpu)
+    streams = {"layer_k": (np.dtype(np.int8), (8,)),
+               "reps": (np.dtype(np.float16), (4,))}
+    cache = DeviceDocCache(2**20, doc_len=480, streams=streams,
+                           page_tokens=requested)
+    assert cache.page_tokens == page
+    assert cache.pools["layer_k"].shape[1:] == (page, 8)
+
+
 def test_page_bucket_widths():
     """bucket() pads to the next power of two, capped at pages_per_doc,
     and a bucketed plan shrinks the table to the batch's longest doc."""
